@@ -445,10 +445,10 @@ def run_dist_parity(quick: bool) -> list[dict]:
 
 
 def dist_worker(quick: bool) -> int:
-    import jax
     from repro.core import DistConfig
+    from repro.core.dist_store import make_mesh
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,))
     # a tight per-(source,target) queue bound so the flash crowd actually
     # exercises switch queue pressure: overflowing queries are dropped
     # and counted as client retries (the quantity this column reports)
@@ -531,6 +531,9 @@ def main(argv=None):
     ap.add_argument("--no-check", action="store_true",
                     help="skip the acceptance gate (exploratory runs)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.dist_worker:
         return dist_worker(args.quick)

@@ -282,6 +282,9 @@ def main(argv=None):
     ap.add_argument("--no-check", action="store_true",
                     help="skip the coordination gate (exploratory runs)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     rows = run_sweep(args.quick)
     rows += run_parity(args.quick)

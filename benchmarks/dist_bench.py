@@ -61,7 +61,6 @@ def _stage_breakdown(drv) -> dict:
 
 def worker(quick: bool) -> int:
     """Forced-8-device measurement (subprocess body)."""
-    import jax
     import numpy as np
 
     from benchmarks.balance_bench import (
@@ -71,9 +70,10 @@ def worker(quick: bool) -> int:
     from repro.cluster import (EpochDriver, make_policy, make_scenario,
                                summarize)
     from repro.core import DistConfig
+    from repro.core.dist_store import make_mesh
     from repro.telemetry import TelemetryConfig
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,))
     scfg = scenario_config(quick)
     kw = scenario_kwargs(SCENARIO, scfg)
     # dist rows fuse the whole run into one control period (the
@@ -185,6 +185,9 @@ def main(argv=None) -> int:
     ap.add_argument("--worker", action="store_true",
                     help=argparse.SUPPRESS)  # internal: the forked mesh run
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.worker:
         return worker(args.quick)

@@ -212,6 +212,9 @@ def main(argv=None) -> int:
     ap.add_argument("--out-dir", default=".")
     ap.add_argument("--no-check", action="store_true")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     rows_p, prob_p = run_parity(args.quick)
     rows_b, prob_b = run_breach(args.quick, args.out_dir)

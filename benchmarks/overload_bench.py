@@ -277,6 +277,9 @@ def main(argv=None):
     ap.add_argument("--no-check", action="store_true",
                     help="skip the survival gate (exploratory runs)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     scenarios = [s for s in args.scenarios.split(",") if s]
     rows = run_matrix(scenarios, args.quick)

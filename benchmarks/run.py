@@ -126,6 +126,9 @@ def main() -> None:
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="also write rows + engine wall-clock to PATH")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.n_ops is not None and args.n_ops < 1:
         ap.error("--n-ops must be >= 1")
     n = args.n_ops if args.n_ops is not None else (2048 if args.quick else 8192)

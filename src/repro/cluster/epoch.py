@@ -59,6 +59,7 @@ per bench row.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -235,6 +236,26 @@ def _merge_unique(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     return out
 
 
+# Records per preload batch.  Each batch costs O(capacity) per shard (the
+# slab merge), whatever its size: at 1M slots a shard's put is ~0.2 s on
+# a TPU v5e, so chunks of one 4,096-op epoch would take minutes to load a
+# million records.  Peak memory depends on this size and the capacity,
+# never on the record count.
+PRELOAD_CHUNK = 1 << 16
+
+
+@partial(jax.jit, static_argnames=("max_scan_results",), donate_argnums=(0,))
+def _preload_chunk(store, directory, keys, opcodes, values, *,
+                   max_scan_results: int):
+    """One preload batch through route -> apply_routed (the directory's
+    counter bumps are discarded: the load phase is not traffic)."""
+    q = C.make_queries(keys, opcodes, values)
+    decision, _ = R.route(directory, q)
+    store, _ = apply_routed(store, q, decision,
+                            max_scan_results=max_scan_results)
+    return store
+
+
 def _jit_cache_size(fn, default: int = 0) -> int:
     cs = getattr(fn, "_cache_size", None)
     return cs() if callable(cs) else default
@@ -272,6 +293,15 @@ class EpochDriver:
             raise ValueError(f"unknown backend {backend!r}")
         if backend == "dist" and mesh is None:
             raise ValueError("backend='dist' needs a mesh")
+        if backend == "dist":
+            n_dev = mesh.shape[(dist_cfg or DistConfig()).axis]
+            if n_dev != cfg.num_nodes:
+                # one storage node per device: the shard-local data plane
+                # treats its slab as one node
+                raise ValueError(
+                    f"backend='dist' places one storage node per device: "
+                    f"num_nodes={cfg.num_nodes} on a mesh axis of {n_dev}"
+                )
         self.backend = backend
         self.fused = fused
         # consistency mode wiring: how routing / hop planning / the
@@ -338,7 +368,18 @@ class EpochDriver:
             # every record on up to r_max chains, plus 2x headroom for skewed
             # placement and widen copies
             capacity = max(256, 2 * scfg.n_records * cfg.r_max // cfg.num_nodes)
-        self.store = make_store(cfg.num_nodes, capacity, scfg.value_dim)
+        if backend == "dist":
+            # each device allocates only its own slab: the whole store
+            # never sits on one device
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self.store = jax.jit(
+                partial(make_store, cfg.num_nodes, capacity, scfg.value_dim),
+                out_shardings=NamedSharding(
+                    mesh, PartitionSpec((dist_cfg or DistConfig()).axis)),
+            )()
+        else:
+            self.store = make_store(cfg.num_nodes, capacity, scfg.value_dim)
         self.directory = directory
         self.load_reg = jnp.zeros((cfg.num_nodes,), jnp.uint32)
         self.sketch = make_sketch(cfg.sketch_width, cfg.sketch_depth)
@@ -521,17 +562,48 @@ class EpochDriver:
 
     # -- setup -------------------------------------------------------------
     def _preload(self):
-        """YCSB load phase: PUT every record through the normal data path."""
+        """YCSB load phase: PUT every record through the normal data path,
+        in chunks of at most ``PRELOAD_CHUNK`` records so peak device
+        memory does not grow with the record count.  The tail chunk is
+        padded with EMPTY-key GETs, which touch nothing.  Records are
+        distinct keys, so the store ends bit-identical to a one-batch
+        load.
+
+        On the dist backend every device sees the whole chunk (the
+        ``allgather`` data plane) and writes only its own slab, so the
+        load runs shard-local, as the served path does."""
         keys, vals = self.scenario.load()
-        q = C.make_queries(
-            jnp.asarray(keys),
-            jnp.full((len(keys),), K.OP_PUT),
-            jnp.asarray(vals),
-        )
-        decision, _ = R.route(self.directory, q)  # discard counter bumps
-        self.store, _ = apply_routed(
-            self.store, q, decision, max_scan_results=self.cfg.max_scan_results
-        )
+        if self.backend == "dist":
+            axis = self._dist_cfg.axis
+            n_dev = self._mesh.shape[axis]
+            dist_load = make_dist_apply(
+                self._mesh, self.directory,
+                DistConfig(axis=axis, strategy="allgather",
+                           max_scan_results=1),
+            )
+
+            def load(store, k, ops, v):
+                return dist_load(store, self.directory,
+                                 C.make_queries(k, ops, v))[0]
+        else:
+            n_dev = 1
+
+            def load(store, k, ops, v):
+                return _preload_chunk(
+                    store, self.directory, k, ops, v,
+                    max_scan_results=self.cfg.max_scan_results)
+        # a whole number of batch slices per device
+        B = -(-min(PRELOAD_CHUNK, len(keys)) // n_dev) * n_dev
+        for i in range(0, len(keys), B):
+            k, v = keys[i:i + B], vals[i:i + B]
+            pad = B - len(k)
+            ops = np.full((B,), K.OP_PUT, np.int32)
+            if pad:
+                ops[len(k):] = K.OP_GET
+                k = np.concatenate([k, np.full((pad,), K.EMPTY_KEY, k.dtype)])
+                v = np.concatenate([v, np.zeros((pad,) + v.shape[1:], v.dtype)])
+            self.store = load(self.store, jnp.asarray(k), jnp.asarray(ops),
+                              jnp.asarray(v))
         ovf = np.asarray(self.store.overflow).astype(np.int64)
         self._last_overflow = int(ovf.sum())
         # per-node overflow floor for capacity-driven splitting (which

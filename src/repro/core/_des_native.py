@@ -1,10 +1,10 @@
 """Build + load the native DES event core (``des_core.c``) via ctypes.
 
 The core is compiled once per source hash with the system C compiler and
-cached next to the package (falling back to the system temp dir, then to
-``None`` — callers degrade to the pure-JAX engine when no toolchain or no
-writable cache location exists).  No Python dependencies are added; only
-``cc`` is invoked, and only on first use.
+cached next to the package in ``_native_cache/`` (gitignored).  No Python
+dependencies are added; only ``cc`` (or ``$CC``) is invoked, and only on
+first use.  A failed build or load raises with the compiler's output:
+callers never fall back silently.
 """
 
 from __future__ import annotations
@@ -12,15 +12,16 @@ from __future__ import annotations
 import ctypes
 import hashlib
 import os
+import shutil
 import subprocess
 import tempfile
 import threading
 from pathlib import Path
 
 _SRC = Path(__file__).with_name("des_core.c")
+_CACHE = Path(__file__).with_name("_native_cache")
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
-_tried = False
 
 _ARGTYPES = [
     ctypes.c_void_p,  # nodes (S,B,H) int32
@@ -44,21 +45,9 @@ _ARGTYPES = [
 ]
 
 
-def _cache_dir() -> Path | None:
-    candidates = (
-        Path(__file__).parent / "_native_cache",
-        Path(tempfile.gettempdir()) / "repro_des_native",
-    )
-    for cand in candidates:
-        try:
-            cand.mkdir(parents=True, exist_ok=True)
-            probe = cand / ".writable"
-            probe.touch()
-            probe.unlink()
-            return cand
-        except OSError:
-            continue
-    return None
+def _so_path() -> Path:
+    tag = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _CACHE / f"des_core_{tag}.so"
 
 
 def _build(src: Path, out: Path) -> None:
@@ -66,41 +55,40 @@ def _build(src: Path, out: Path) -> None:
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=str(out.parent))
     os.close(fd)
     try:
-        subprocess.run(
+        r = subprocess.run(
             [cc, "-O2", "-shared", "-fPIC", "-o", tmp, str(src)],
-            check=True,
             capture_output=True,
+            text=True,
             timeout=120,
         )
+        if r.returncode != 0:
+            raise RuntimeError(
+                f"{cc} failed to build {src.name} (exit {r.returncode}):\n"
+                f"{r.stderr}"
+            )
         os.replace(tmp, out)  # atomic under concurrent builders
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
 
 
-def load() -> ctypes.CDLL | None:
-    """The compiled core, or None when unavailable (no cc / no cache dir)."""
-    global _lib, _tried
+def load() -> ctypes.CDLL:
+    """The compiled core.  Raises if it cannot be built or loaded."""
+    global _lib
     with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        _tried = True
-        try:
-            tag = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
-            cache = _cache_dir()
-            if cache is None:
-                return None
-            so = cache / f"des_core_{tag}.so"
+        if _lib is None:
+            so = _so_path()
             if not so.exists():
+                _CACHE.mkdir(parents=True, exist_ok=True)
                 _build(_SRC, so)
             lib = ctypes.CDLL(str(so))
             lib.des_simulate_batch.restype = None
             lib.des_simulate_batch.argtypes = _ARGTYPES
             _lib = lib
-        except Exception:
-            _lib = None
         return _lib
 
 
 def available() -> bool:
-    return load() is not None
+    """Whether the core is built or a C compiler exists to build it."""
+    return _so_path().exists() or shutil.which(
+        os.environ.get("CC", "cc")) is not None

@@ -33,8 +33,10 @@ engine instead
   - ``native``: a ~100-line C core (``des_core.c``) compiled on first use
     with the system ``cc`` and driven via :mod:`ctypes` — no Python-level
     per-event work at all;
-  - ``jax``: an XLA ``while_loop`` over the same event recurrence (always
-    available; used when no C toolchain exists).
+  - ``jax``: an XLA ``while_loop`` over the same event recurrence (used
+    when no C compiler exists).  It always runs on the host CPU device,
+    also where the default device is an accelerator: the loop is f64
+    and strictly sequential.
 
 Exactness contract
 ------------------
@@ -52,7 +54,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import _des_native
 from repro.core.coordination import NO_HOP, HopPlan
@@ -63,6 +65,7 @@ __all__ = [
     "stack_plans",
     "compact_plans",
     "available_backends",
+    "backend_info",
 ]
 
 
@@ -163,12 +166,26 @@ def _resolve_backend(backend: str | None) -> str:
             )
     if backend == "native" and not _des_native.available():
         raise RuntimeError(
-            "native DES backend requested but no C toolchain / cache dir "
-            "is available; use backend='jax'"
+            "native DES backend requested but no C compiler is available; "
+            "use backend='jax'"
         )
     if backend not in ("native", "jax"):
         raise ValueError(f"unknown DES backend {backend!r}")
     return backend
+
+
+def _jax_device():
+    return jax.devices("cpu")[0]
+
+
+def backend_info(backend: str | None = None) -> tuple[str, str]:
+    """``(backend, where it runs)`` for a backend selection, e.g.
+    ``("native", "host C core")`` or ``("jax", "TFRT_CPU_0")``."""
+    name = _resolve_backend(backend)
+    if name == "native":
+        _des_native.load()
+        return name, "host C core"
+    return name, str(_jax_device())
 
 
 # ---------------------------------------------------------------------------
@@ -322,7 +339,7 @@ def _run_jax(nodes_c, service_c, n_hops, arrivals, *, K, N, link, think,
     finish = np.zeros((S, B), np.float64)
     issue = np.zeros((S, B), np.float64)
     hops = np.zeros((S, B, H), np.float64) if want_hops else None
-    with enable_x64():
+    with jax.default_device(_jax_device()), enable_x64():
         link64 = jnp.float64(link)
         think64 = jnp.float64(think)
         for s in range(S):

@@ -480,7 +480,7 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
         in_specs = (store_spec, dir_spec, q_spec)
         out_specs = (store_spec, resp_spec, dir_spec, metric_spec)
 
-    fn = shard_map_compat(entry, mesh, in_specs, out_specs)
+    fn = _shard_map(entry, mesh, in_specs, out_specs)
     return jax.jit(fn)
 
 
@@ -610,18 +610,32 @@ def make_dist_period(mesh, directory_template: Directory, cfg: DistConfig,
                 P(), P())
     out_specs = (store_spec, P(), P(), P(), P(), P(), P(), P(),
                  P(), P(), P(), P(), P(), P(), P(), P())
-    fn = shard_map_compat(period_device, mesh, in_specs, out_specs)
+    fn = _shard_map(period_device, mesh, in_specs, out_specs)
     return jax.jit(fn, donate_argnums=(0, 2, 3, 4, 5, 6, 7))
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax releases: >= 0.5 exposes ``jax.shard_map``
-    (``check_vma=``); older releases only have
-    ``jax.experimental.shard_map.shard_map`` (``check_rep=``).  Shared by
-    every shard_map user in the repo (dist store, DP train step)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+def make_mesh(shape: tuple[int, ...],
+              axis_names: tuple[str, ...] = ("data",), *, devices=None):
+    """The one mesh builder of the repo (dist store, benches, tests),
+    over the first devices of ``devices`` (default ``jax.devices()``).
+
+    Axes are ``AxisType.Auto``: the data plane partitions by hand inside
+    ``shard_map`` and keeps the control state replicated, and under JAX's
+    default ``Explicit`` axes the replicated ops on sharding-typed values
+    (``_node_ops``' scatter, the per-epoch step's slicing) are rejected."""
+    devices = list(jax.devices() if devices is None else devices)
+    n = 1
+    for d in shape:
+        n *= d
+    if n > len(devices):
+        raise ValueError(f"mesh {shape} needs {n} devices, have {len(devices)}")
+    return jax.make_mesh(
+        tuple(shape), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+        devices=devices[:n],
+    )
+
+
+def _shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)

@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import keys as K
-from repro.core.store import StoreState, _compact_sorted, slab_put, slab_delete
+from repro.core.store import (StoreState, _compact_sorted, delete_range,
+                              in_range, put_sorted)
 
 EMPTY = K.EMPTY_KEY
 
@@ -41,11 +42,11 @@ class MigrationOp:
 
 def _extract_range(slab_keys: jnp.ndarray, slab_vals: jnp.ndarray, lo, hi):
     """All entries with key in [lo, hi], EMPTY-padded to capacity."""
-    in_range = (slab_keys >= lo) & (slab_keys <= hi) & (slab_keys != EMPTY)
-    ex_keys = jnp.where(in_range, slab_keys, EMPTY)
+    hit = in_range(slab_keys, lo, hi)
+    ex_keys = jnp.where(hit, slab_keys, EMPTY)
     # the hits are a sorted subsequence of the sorted slab: gather-compact
     # them to a prefix instead of re-sorting the whole slab
-    return _compact_sorted(ex_keys, slab_vals, in_range)
+    return _compact_sorted(ex_keys, slab_vals, hit)
 
 
 @partial(jax.jit, static_argnames=("move",))
@@ -56,12 +57,14 @@ def apply_migration(store: StoreState, lo, hi, src: jnp.ndarray, dst: jnp.ndarra
     hi = jnp.asarray(hi, jnp.uint32)
     ex_keys, ex_vals = _extract_range(store.keys[src], store.values[src], lo, hi)
 
-    dst_keys, dst_vals, dropped = slab_put(store.keys[dst], store.values[dst], ex_keys, ex_vals)
+    # the extracted range is sorted and distinct: no dedupe sort (a sort
+    # of a whole slab is minutes of TPU compile at a million slots)
+    dst_keys, dst_vals, dropped = put_sorted(store.keys[dst], store.values[dst], ex_keys, ex_vals)
     keys = store.keys.at[dst].set(dst_keys)
     values = store.values.at[dst].set(dst_vals)
 
     if move:
-        src_keys, src_vals = slab_delete(keys[src], values[src], ex_keys)
+        src_keys, src_vals = delete_range(keys[src], values[src], lo, hi)
         keys = keys.at[src].set(src_keys)
         values = values.at[src].set(src_vals)
 
@@ -73,10 +76,7 @@ def apply_reclaim(store: StoreState, lo, hi, node: jnp.ndarray) -> StoreState:
     """Delete [lo, hi] at ``node`` (chain-narrowing space reclamation)."""
     lo = jnp.asarray(lo, jnp.uint32)
     hi = jnp.asarray(hi, jnp.uint32)
-    slab_keys = store.keys[node]
-    in_range = (slab_keys >= lo) & (slab_keys <= hi) & (slab_keys != EMPTY)
-    del_keys = jnp.where(in_range, slab_keys, EMPTY)
-    new_keys, new_vals = slab_delete(slab_keys, store.values[node], del_keys)
+    new_keys, new_vals = delete_range(store.keys[node], store.values[node], lo, hi)
     return StoreState(
         keys=store.keys.at[node].set(new_keys),
         values=store.values.at[node].set(new_vals),

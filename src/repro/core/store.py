@@ -10,8 +10,9 @@ parallel value array.  Sorted order gives O(log C) batched lookups
 a searchsorted **rank merge** of the two already-sorted runs (the slab and
 the deduped batch) — the moral equivalent of an SST memtable merge, at
 O(C+B) gather work (plus O(B log) binary searches) instead of a full
-O((C+B) log(C+B)) sort of the concatenation, and with no XLA scatter on
-the hot path (CPU scatters serialize).  The merge reproduces the old
+O((C+B) log(C+B)) sort of the concatenation; the only scatter is the
+B-row in-place overwrite of keys a PUT batch already holds.  The merge
+reproduces the old
 sort-and-truncate layout exactly on the live prefix (asserted in
 ``tests/test_store_merge.py``); dead tail slots now hold zeroed values
 instead of stale garbage — a deliberate tightening.  The jnp oracle
@@ -111,6 +112,24 @@ def make_store(num_shards: int, capacity: int, value_dim: int) -> StoreState:
 # ---------------------------------------------------------------------------
 
 
+_CUMSUM_BLOCK = 1024
+
+
+def _cumsum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum of a 1-D integer array, in blocks of
+    ``_CUMSUM_BLOCK``: a row-wise cumsum plus a cumsum of the row totals.
+    XLA's TPU compiler spends ~30 s on one ``jnp.cumsum`` over a
+    million-slot slab and about a second on this form; integer sums make
+    the two bit-identical."""
+    n = x.shape[0]
+    if n <= _CUMSUM_BLOCK:
+        return jnp.cumsum(x)
+    m = -(-n // _CUMSUM_BLOCK) * _CUMSUM_BLOCK
+    rows = jnp.cumsum(jnp.pad(x, (0, m - n)).reshape(-1, _CUMSUM_BLOCK), axis=1)
+    tot = rows[:, -1]
+    return (rows + (jnp.cumsum(tot) - tot)[:, None]).reshape(-1)[:n]
+
+
 def _compact_sorted(keys: jnp.ndarray, vals: jnp.ndarray, live: jnp.ndarray):
     """Gather the ``live`` entries (a sorted-in-index-order subsequence) to
     a sorted prefix; EMPTY keys / zero values beyond.
@@ -120,7 +139,7 @@ def _compact_sorted(keys: jnp.ndarray, vals: jnp.ndarray, live: jnp.ndarray):
     — O(n log n) binary searches, no sort, no scatter.
     """
     n = keys.shape[0]
-    cum = jnp.cumsum(live.astype(jnp.int32))
+    cum = _cumsum(live.astype(jnp.int32))
     d = jnp.arange(n, dtype=jnp.int32)
     src = jnp.minimum(jnp.searchsorted(cum, d + 1, side="left"), n - 1)
     in_live = d < cum[-1]
@@ -136,11 +155,14 @@ def _dedupe_last_write(qkeys: jnp.ndarray, qvals: jnp.ndarray):
     dropped: live entries are a sorted prefix, EMPTY/zero beyond.
     """
     B = qkeys.shape[0]
-    # primary: key asc; secondary: original index desc (later writes first)
-    perm = jnp.lexsort((-jnp.arange(B, dtype=jnp.int32), qkeys))
-    sk, sv = qkeys[perm], qvals[perm]
-    first = jnp.concatenate([jnp.ones((1,), bool), sk[1:] != sk[:-1]])
-    sk = jnp.where(first, sk, EMPTY)
+    # one stable sort on the key (equal keys keep batch order, so a run's
+    # last entry is the last write): a lexsort on (key, -index) takes the
+    # TPU compiler minutes at 64K entries
+    sk, perm = jax.lax.sort((qkeys, jnp.arange(B, dtype=jnp.int32)),
+                            num_keys=1, is_stable=True)
+    sv = qvals[perm]
+    last = jnp.concatenate([sk[1:] != sk[:-1], jnp.ones((1,), bool)])
+    sk = jnp.where(last, sk, EMPTY)
     return _compact_sorted(sk, sv, sk != EMPTY)
 
 
@@ -225,6 +247,18 @@ def slab_delete(slab_keys: jnp.ndarray, slab_vals: jnp.ndarray, del_keys: jnp.nd
     return _compact_sorted(new_keys, slab_vals, new_keys != EMPTY)
 
 
+def in_range(slab_keys: jnp.ndarray, lo, hi) -> jnp.ndarray:
+    """Live slab entries with key in [lo, hi]."""
+    return (slab_keys >= lo) & (slab_keys <= hi) & (slab_keys != EMPTY)
+
+
+def delete_range(slab_keys: jnp.ndarray, slab_vals: jnp.ndarray, lo, hi):
+    """:func:`slab_delete` of every key in [lo, hi]: the hits are known
+    without a sort of the deleted keys."""
+    live = (slab_keys != EMPTY) & ~in_range(slab_keys, lo, hi)
+    return _compact_sorted(jnp.where(live, slab_keys, EMPTY), slab_vals, live)
+
+
 def _merge_sorted_runs(ak, av, bk, bv, out_len: int):
     """Gather-style stable merge of two sorted runs (EMPTY tails sink, run-a
     holes ahead of run-b holes, matching the old stable concat-argsort).
@@ -251,22 +285,35 @@ def _merge_sorted_runs(ak, av, bk, bv, out_len: int):
 def slab_put(slab_keys: jnp.ndarray, slab_vals: jnp.ndarray, put_keys: jnp.ndarray, put_vals: jnp.ndarray):
     """Insert/overwrite a batch. Returns (keys, vals, dropped_count).
 
-    The slab (overwritten entries evicted, survivors gather-compacted) and
-    the deduped batch are two sorted runs; a searchsorted rank merge
-    (:func:`_merge_sorted_runs`) produces the combined sorted slab in
-    O(C+B) gather work — no log-factor sort of the concatenation, same
-    sorted-prefix invariant.  Capacity overflow drops the largest keys and
-    reports the dropped count, as before.
+    The slab must be compact (live keys a sorted prefix, EMPTY tail), as
+    every store operation leaves it.  Keys already in the slab take their
+    new value in place (a B-row scatter); the new keys form a second
+    sorted run, and a searchsorted rank merge (:func:`_merge_sorted_runs`)
+    produces the combined sorted slab in O(C+B) gather work — no
+    log-factor sort of the concatenation and no O(C) compaction of the
+    slab.  Capacity overflow drops the largest keys and reports the
+    dropped count.
     """
-    C = slab_keys.shape[0]
     pk, pv = _dedupe_last_write(put_keys, put_vals)
-    # evict slab entries being overwritten
-    overwritten = _member_sorted(pk, slab_keys)
-    live = ~overwritten & (slab_keys != EMPTY)
-    ak, av = _compact_sorted(slab_keys, slab_vals, live)
+    return put_sorted(slab_keys, slab_vals, pk, pv)
+
+
+def put_sorted(slab_keys: jnp.ndarray, slab_vals: jnp.ndarray,
+               pk: jnp.ndarray, pv: jnp.ndarray):
+    """:func:`slab_put` of a batch that is already sorted with distinct
+    keys, live keys a prefix (as :func:`_dedupe_last_write` or a
+    range extracted from a slab leaves it): no sort."""
+    C = slab_keys.shape[0]
+    pos = jnp.minimum(jnp.searchsorted(slab_keys, pk), C - 1)
+    hit = (slab_keys[pos] == pk) & (pk != EMPTY)
+    slab_vals = slab_vals.at[jnp.where(hit, pos, C)].set(pv, mode="drop")
+    nk, nv = _compact_sorted(jnp.where(hit, EMPTY, pk), pv, ~hit & (pk != EMPTY))
     # only the C smallest merged entries survive truncation: merge those
-    out_keys, out_vals = _merge_sorted_runs(ak, av, pk, pv, C)
-    n_live = jnp.sum(live.astype(jnp.int32)) + jnp.sum((pk != EMPTY).astype(jnp.int32))
+    out_keys, out_vals = _merge_sorted_runs(slab_keys, slab_vals, nk, nv, C)
+    # dead tail slots hold zeros, whatever the slab's tail held
+    out_vals = jnp.where((out_keys != EMPTY)[:, None], out_vals, 0.0)
+    n_live = (jnp.sum((slab_keys != EMPTY).astype(jnp.int32))
+              + jnp.sum((nk != EMPTY).astype(jnp.int32)))
     dropped = jnp.maximum(n_live - C, 0)
     return out_keys, out_vals, dropped
 
@@ -294,28 +341,52 @@ def shard_apply(
     is_scan = (q.opcode == K.OP_SCAN) & read_mine
     is_del = (q.opcode == K.OP_DEL) & write_mine
     is_put = (q.opcode == K.OP_PUT) & write_mine
+    B, V = q.value.shape
 
     # --- reads against pre-batch state ---
     get_vals, get_found = slab_get(slab_keys, slab_vals, jnp.where(is_get, q.key, EMPTY))
-    sk, sv, scount = slab_scan(
-        slab_keys,
-        slab_vals,
-        jnp.where(is_scan, q.key, EMPTY),
-        jnp.where(is_scan, q.end_key, jnp.zeros_like(q.end_key)),
-        max_scan_results,
-    )
+
+    # scans, deletes and puts cost O(C) per shard (slab pad copy,
+    # compaction, merge): each is skipped when the batch holds none.  The
+    # predicates read the whole batch, so they stay a cond also where a
+    # caller vmaps over shards (a per-shard predicate would turn into a
+    # select of both branches there).
+    def scan(_):
+        return slab_scan(
+            slab_keys,
+            slab_vals,
+            jnp.where(is_scan, q.key, EMPTY),
+            jnp.where(is_scan, q.end_key, jnp.zeros_like(q.end_key)),
+            max_scan_results,
+        )
+
+    def no_scan(_):
+        return (jnp.full((B, max_scan_results), EMPTY, slab_keys.dtype),
+                jnp.zeros((B, max_scan_results, V), slab_vals.dtype),
+                jnp.zeros((B,), jnp.int32))
+
+    sk, sv, scount = jax.lax.cond(jnp.any(q.opcode == K.OP_SCAN), scan,
+                                  no_scan, None)
     scount = jnp.where(is_scan, scount, 0)
     sk = jnp.where(is_scan[:, None], sk, EMPTY)
     sv = jnp.where(is_scan[:, None, None], sv, 0.0)
 
     # --- deletes ---
-    del_found = _member_sorted(slab_keys, jnp.where(is_del, q.key, EMPTY))
-    slab_keys, slab_vals = slab_delete(slab_keys, slab_vals, jnp.where(is_del, q.key, EMPTY))
+    del_keys = jnp.where(is_del, q.key, EMPTY)
+    del_found = _member_sorted(slab_keys, del_keys)
+    slab_keys, slab_vals = jax.lax.cond(
+        jnp.any(q.opcode == K.OP_DEL),
+        lambda kv: slab_delete(*kv, del_keys), lambda kv: kv,
+        (slab_keys, slab_vals))
 
     # --- puts ---
-    slab_keys, slab_vals, dropped = slab_put(
-        slab_keys, slab_vals, jnp.where(is_put, q.key, EMPTY), jnp.where(is_put[:, None], q.value, 0.0)
-    )
+    put_keys = jnp.where(is_put, q.key, EMPTY)
+    put_vals = jnp.where(is_put[:, None], q.value, 0.0)
+    slab_keys, slab_vals, dropped = jax.lax.cond(
+        jnp.any(q.opcode == K.OP_PUT),
+        lambda kv: slab_put(*kv, put_keys, put_vals),
+        lambda kv: (*kv, jnp.zeros((), jnp.int32)),
+        (slab_keys, slab_vals))
 
     resp = Responses(
         value=get_vals,
@@ -337,7 +408,7 @@ def apply_routed(
     """Apply a routed batch to every shard (single-program simulation path).
 
     The distributed twin lives in ``repro.core.dist_store`` (shard_map); this
-    vmapped form is bit-identical and is the oracle for it.  Reads are served
+    form is bit-identical and is the oracle for it.  Reads are served
     by the routed target (the chain tail); writes are applied by every live
     chain member — the end state chain replication converges to (§4.1.2).
     """
@@ -355,15 +426,29 @@ def apply_routed(
             slab_keys, slab_vals, q, read_mine, write_mine, max_scan_results=max_scan_results
         )
 
-    new_keys, new_vals, dropped, resps = jax.vmap(one_shard)(store.keys, store.values, shard_ids)
+    # one shard at a time, not vmapped: a slab merge's temporaries are
+    # O(capacity).  At 8 x 1M slots of 128-byte values and a 4,096-op
+    # batch, a v5e compile's memory_analysis gives 17.31 GiB for the
+    # vmapped form (over a 16 GB chip) and 4.53 GiB for this one
+    new_keys, new_vals, dropped, resps = jax.lax.map(
+        lambda a: one_shard(*a), (store.keys, store.values, shard_ids))
 
-    # combine per-shard responses: each read is answered by exactly one shard
-    owner = jax.nn.one_hot(decision.target, N, dtype=jnp.float32)  # (B, N)
-    value = jnp.einsum("nbv,bn->bv", resps.value, owner)
-    found = jnp.einsum("nb,bn->b", resps.found.astype(jnp.float32), owner) > 0
-    scan_values = jnp.einsum("nbsv,bn->bsv", resps.scan_values, owner)
-    scan_count = jnp.einsum("nb,bn->b", resps.scan_count.astype(jnp.float32), owner).astype(jnp.int32)
-    # keys: pick via argmax owner (uint gather, einsum would mangle the sentinel)
+    # combine per-shard responses: each read is answered by exactly one
+    # shard, the routed target.  A gather, not a one-hot matmul: on a TPU
+    # an f32 matmul at default precision rounds the values through bf16.
+    # Unrouted queries (target NO_NODE) read as a miss, as before.
+    routed = (decision.target >= 0) & (decision.target < N)
+    owner = jnp.where(routed, decision.target, 0).astype(jnp.int32)
+    b = jnp.arange(owner.shape[0])
+
+    def pick(x):
+        hit = routed.reshape(routed.shape + (1,) * (x.ndim - 2))
+        return jnp.where(hit, x[owner, b], jnp.zeros((), x.dtype))
+
+    value = pick(resps.value)
+    found = pick(resps.found)
+    scan_values = pick(resps.scan_values)
+    scan_count = pick(resps.scan_count)
     scan_keys = jnp.take_along_axis(
         resps.scan_keys, decision.target[None, :, None].astype(jnp.int32), axis=0
     )[0]

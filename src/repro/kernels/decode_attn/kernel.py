@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; accept both
-_compiler_params = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -125,7 +123,7 @@ def decode_attn_pallas(
             pltpu.VMEM((Hkv, group, 1), jnp.float32),
             pltpu.VMEM((Hkv, group, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

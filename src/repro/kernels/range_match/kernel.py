@@ -289,44 +289,43 @@ def _kernel_stale(mvals_ref, opcodes_ref, sw_ref, lo_ref, hi_ref, chains_ref,
     count — a handful); rather than gathering a (Bb, 128, Spad) per-packet
     row (dynamic-vector gathers are slow on TPU), the tile runs the
     interval match against *every* switch's table and broadcast-selects by
-    the packet's switch id — W small static min-reduces, all lane-parallel
-    VPU work, bit-identical to the gathered-row jnp oracle because each
-    packet's result only ever reads its own switch's rows.
+    the packet's switch id — bit-identical to the gathered-row jnp oracle
+    because each packet's result only ever reads its own switch's rows.
+    The switches are a ``fori_loop``, not an unrolled loop, so only one
+    switch's (Bb, 128, Spad) intermediates are live in VMEM at a time.
+    Per-switch tables arrive as (W, rows, Spad) and are indexed on the
+    leading axis.
     """
     mvals = mvals_ref[...]            # (Bb, 128) uint32
     opcodes = opcodes_ref[...]        # (Bb, 128) int32
     sw = sw_ref[...]                  # (Bb, 128) int32 ingress switch ids
-    lo = lo_ref[...]                  # (W, Spad) uint32, live/dead-masked
-    hi = hi_ref[...]                  # (W, Spad) uint32
-    chains = chains_ref[...]          # (W * r_max, Spad) int32
-    clen = clen_ref[...]              # (W, Spad) int32
-    version = version_ref[...]        # (W, Spad) int32 (u32 bit-cast)
     committed = committed_ref[...]    # (1, Spad) int32 (u32 bit-cast)
-
     is_write = (opcodes == 1) | (opcodes == 2)
-    sridx = None
-    server = None
-    divergent = None
-    for w in range(n_switches):
-        ridx_w = _slot_match_tile(mvals, lo[w:w + 1], hi[w:w + 1], num_slots)
-        cols_w = _gather_rows_tile(ridx_w, chains[w * r_max:(w + 1) * r_max])
-        (clen_w,) = _gather_rows_tile(ridx_w, clen[w:w + 1])
+
+    def match(w):
+        # lo/hi (1, Spad) uint32 live/dead-masked; chains (r_max, Spad);
+        # clen, version (1, Spad) int32
+        ridx_w = _slot_match_tile(mvals, lo_ref[w], hi_ref[w], num_slots)
+        cols_w = _gather_rows_tile(ridx_w, chains_ref[w])
+        (clen_w,) = _gather_rows_tile(ridx_w, clen_ref[w])
         tail_w = _select_pos_tile(cols_w, clen_w - 1)
         server_w = jnp.where(is_write, cols_w[0], tail_w)
-        (ver_w,) = _gather_rows_tile(ridx_w, version[w:w + 1])
+        (ver_w,) = _gather_rows_tile(ridx_w, version_ref[w])
         (com_w,) = _gather_rows_tile(ridx_w, committed)
-        div_w = ver_w != com_w
-        if w == 0:
-            sridx, server, divergent = ridx_w, server_w, div_w
-        else:
-            here = sw == w
-            sridx = jnp.where(here, ridx_w, sridx)
-            server = jnp.where(here, server_w, server)
-            divergent = jnp.where(here, div_w, divergent)
+        # int32, not bool: Mosaic cannot select between i1 vectors
+        return ridx_w, server_w, (ver_w != com_w).astype(jnp.int32)
 
+    def fold(w, acc):
+        here = sw == w
+        return tuple(jnp.where(here, new, old)
+                     for new, old in zip(match(w), acc))
+
+    # switch 0 seeds the select, so a packet outside [0, W) reads switch 0
+    sridx, server, divergent = jax.lax.fori_loop(1, n_switches, fold,
+                                                 match(0))
     sridx_ref[...] = sridx
     server_ref[...] = server
-    divergent_ref[...] = divergent.astype(jnp.int32)
+    divergent_ref[...] = divergent
 
 
 def _kernel_apply(mvals_ref, opcodes_ref, u1_ref, u2_ref, qkeys_ref,
@@ -417,7 +416,7 @@ def range_match_stale_pallas(
     num_slots: int,
     r_max: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Launch the replicated-directory (stale-table) match-action kernel.
 
@@ -441,8 +440,10 @@ def range_match_stale_pallas(
         jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
     )
-    whole_w = lambda i: (0, 0)
+    whole = lambda i: (0, 0)
+    whole_w = lambda i: (0, 0, 0)
     tile = lambda i: (i, 0)
+    per_switch = pl.BlockSpec((n_switches, 1, spad), whole_w)
     sridx, server, divergent = pl.pallas_call(
         kernel,
         grid=grid,
@@ -450,12 +451,12 @@ def range_match_stale_pallas(
             pl.BlockSpec((block_rows, LANES), tile),
             pl.BlockSpec((block_rows, LANES), tile),
             pl.BlockSpec((block_rows, LANES), tile),
-            pl.BlockSpec((n_switches, spad), whole_w),
-            pl.BlockSpec((n_switches, spad), whole_w),
-            pl.BlockSpec((n_switches * r_max, spad), whole_w),
-            pl.BlockSpec((n_switches, spad), whole_w),
-            pl.BlockSpec((n_switches, spad), whole_w),
-            pl.BlockSpec((1, spad), whole_w),
+            per_switch,
+            per_switch,
+            pl.BlockSpec((n_switches, r_max, spad), whole_w),
+            per_switch,
+            per_switch,
+            pl.BlockSpec((1, spad), whole),
         ],
         out_specs=(
             pl.BlockSpec((block_rows, LANES), tile),
@@ -468,11 +469,11 @@ def range_match_stale_pallas(
         mvals.reshape(rows, LANES),
         opcodes.reshape(rows, LANES),
         sw.reshape(rows, LANES),
-        lo_w,
-        hi_w,
-        chains_w,
-        clen_w,
-        version_w,
+        lo_w.reshape(n_switches, 1, spad),
+        hi_w.reshape(n_switches, 1, spad),
+        chains_w.reshape(n_switches, r_max, spad),
+        clen_w.reshape(n_switches, 1, spad),
+        version_w.reshape(n_switches, 1, spad),
         committed.reshape(1, spad),
     )
     return sridx.reshape(B), server.reshape(B), divergent.reshape(B)
@@ -495,7 +496,7 @@ def range_match_apply_pallas(
     num_slots: int,
     slab_len: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool,
     gather_rows: bool | None = None,
 ):
     """Launch the fused route→apply kernel.
@@ -586,7 +587,7 @@ def slab_lookup_pallas(
     *,
     slab_len: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool,
     gather_rows: bool | None = None,
 ):
     """Launch the standalone slab-lookup kernel (two-kernel baseline's
@@ -642,7 +643,7 @@ def range_match_spread_dirty_pallas(
     *,
     num_slots: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Launch the CRAQ apportioned-read match-action kernel.
 
@@ -722,7 +723,7 @@ def range_match_spread_pallas(
     *,
     num_slots: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Launch the load-aware match-action kernel (p2c read spreading).
 
@@ -789,7 +790,7 @@ def range_match_pallas(
     *,
     num_slots: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Launch the match-action kernel.  B must be a multiple of 128*block_rows
     (ops.py pads).  Returns (ridx (B,), target (B,), chain (r_max, B))."""

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; accept both
-_compiler_params = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, s0_ref, y_ref, fs_ref, state,
@@ -127,7 +125,7 @@ def ssd_chunk_pallas(
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((H, P, N), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

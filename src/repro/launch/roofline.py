@@ -32,7 +32,9 @@ import jax
 import numpy as np
 
 from repro.configs import SHAPES, ARCH_IDS, get_config
-from repro.launch.mesh import PEAK_FLOPS_BF16, HBM_BW, ICI_BW
+from repro.launch.mesh import device_peaks
+
+TARGET_KIND = "TPU v5 lite"  # the dry-run planner's target chip
 
 
 def param_counts(arch: str) -> tuple[float, float]:
@@ -85,9 +87,10 @@ def analyze_cell(key: str, cell: dict) -> dict | None:
     bytes_dev = src["bytes_per_device"]
     wire = src.get("wire_bytes", cell["collectives"]["wire_bytes"])
 
-    t_compute = flops_dev / PEAK_FLOPS_BF16
-    t_memory = bytes_dev / HBM_BW
-    t_coll = wire / ICI_BW
+    peaks = device_peaks(TARGET_KIND)
+    t_compute = flops_dev / peaks["flops_bf16"]
+    t_memory = bytes_dev / peaks["hbm_bw"]
+    t_coll = wire / peaks["ici_bw"]
     bound = max(
         ("compute", t_compute), ("memory", t_memory), ("collective", t_coll),
         key=lambda kv: kv[1],
@@ -96,7 +99,7 @@ def analyze_cell(key: str, cell: dict) -> dict | None:
     mf = model_flops(arch, shape_name)
     hlo_total = flops_dev * n_dev
     useful = mf / max(hlo_total, 1.0)
-    ideal = mf / (n_dev * PEAK_FLOPS_BF16)
+    ideal = mf / (n_dev * peaks["flops_bf16"])
     frac = ideal / max(t_compute, t_memory, t_coll, 1e-30)
 
     return {

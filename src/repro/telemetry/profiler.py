@@ -13,14 +13,16 @@ Two halves, both feeding the ROADMAP's dist-fusion / roofline items:
   ``range_match_spread_dirty`` / ``range_match_apply`` — PR 8's fused
   route→apply — / ``range_match_stale`` — PR 9's replicated-tier stale
   lookup), feed the compiled HLO through
-  ``launch/hlo_stats.analyze_hlo`` and place each against the
-  ``launch/mesh`` TPU v5e peaks (197 TF/s bf16, 819 GB/s HBM).  Off-TPU
-  the reference (non-Pallas) implementation is analyzed — it is
-  bit-identical math, so the op/byte counts are the planning view the
-  roofline needs; on TPU pass ``use_pallas=True`` for the kernel build.
+  ``launch/hlo_stats.analyze_hlo`` and place each against the peaks of
+  the running chip from the ``launch/mesh.PEAKS`` table, keyed by the
+  ``device_kind`` of ``jax.devices()[0]`` (a kind with no entry is an
+  error).  Pass ``use_pallas=True`` on the TPU for the kernel build; the
+  reference (non-Pallas) implementation is the same math.
 
 CLI: ``PYTHONPATH=src python -m repro.telemetry.profiler --json
-BENCH_kernel_roofline.json`` writes the committed roofline table.
+BENCH_kernel_roofline.json`` writes the roofline table, priced against
+the running chip; on a device with no peak table (the CPU) it exits
+with an error.
 """
 
 from __future__ import annotations
@@ -31,7 +33,7 @@ import time
 import numpy as np
 
 from repro.launch.hlo_stats import analyze_hlo
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import device_peaks
 
 
 class StageTimers:
@@ -125,8 +127,12 @@ def kernel_roofline_rows(*, batch: int = 4096, num_ranges: int = 64,
                          r_max: int = 4, n_slots: int | None = None,
                          use_pallas: bool = False, seed: int = 0,
                          measure_iters: int = 5) -> list[dict]:
-    """Compile each routing kernel and return its roofline row."""
+    """Compile each routing kernel and return its roofline row against
+    the peaks of the running device's kind."""
     import jax
+
+    kind = jax.devices()[0].device_kind
+    peaks = device_peaks(kind)
 
     thunks = _kernel_thunks(
         batch=batch, num_ranges=num_ranges, num_nodes=num_nodes,
@@ -150,11 +156,12 @@ def kernel_roofline_rows(*, batch: int = 4096, num_ranges: int = 64,
             jax.block_until_ready(fn())
             times.append(time.perf_counter() - t0)
         wall_us = float(np.median(times) * 1e6)
-        t_compute_us = flops / PEAK_FLOPS_BF16 * 1e6
-        t_memory_us = bytes_ / HBM_BW * 1e6
+        t_compute_us = flops / peaks["flops_bf16"] * 1e6
+        t_memory_us = bytes_ / peaks["hbm_bw"] * 1e6
         rows.append({
             "kernel": name,
             "impl": "pallas" if use_pallas else "ref",
+            "device_kind": kind,
             "batch": batch,
             "n_slots": 2 * num_ranges if n_slots is None else n_slots,
             "flops": flops,
@@ -202,8 +209,10 @@ def main(argv=None) -> int:
     print(fmt_roofline_md(rows))
     if args.json:
         with open(args.json, "w") as f:
-            json.dump({"rows": rows, "peak_flops": PEAK_FLOPS_BF16,
-                       "hbm_bw": HBM_BW}, f, indent=1)
+            json.dump({"rows": rows,
+                       "device_kind": rows[0]["device_kind"],
+                       "peaks": device_peaks(rows[0]["device_kind"])},
+                      f, indent=1)
         print(f"wrote {args.json}")
     return 0
 

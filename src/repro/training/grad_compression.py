@@ -46,9 +46,7 @@ def compressed_psum(grads, err, axis_names):
         deq = dequantize_int8(q, scale)
         new_e = gf - deq
         total = jax.lax.psum(deq, axis_names)
-        # psum of 1 == axis size product; works on every jax release
-        # (jax.lax.axis_size only exists on >= 0.5)
-        n = jax.lax.psum(1, axis_names)
+        n = jax.lax.axis_size(axis_names)
         return (total / n).astype(g.dtype), new_e.astype(e.dtype)
 
     out = jax.tree.map(one, grads, err)

@@ -142,14 +142,13 @@ def make_dp_train_step(cfg: ArchConfig, tcfg: TrainConfig, mesh, batch_template)
             metrics,
         )
 
-    from repro.core.dist_store import shard_map_compat
-
     dp = P(tcfg.dp_axes)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_step,
-        mesh,
-        (P(), dp, jax.tree.map(lambda _: dp, batch_template)),
-        (P(), dp, P()),
+        mesh=mesh,
+        in_specs=(P(), dp, jax.tree.map(lambda _: dp, batch_template)),
+        out_specs=(P(), dp, P()),
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -11,6 +11,7 @@ from repro import core as C
 from repro.core import directory as D
 from repro.core import keys as K
 from repro.core.coordination import NO_HOP
+from repro.core.dist_store import make_mesh
 from repro.kernels.range_match.ops import range_match_spread
 
 from repro.cluster import (
@@ -480,7 +481,7 @@ def test_driver_rejects_bad_backend():
 
 
 def test_dist_backend_single_device_mesh():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,))
     scfg = ScenarioConfig(n_epochs=2, epoch_ops=128, n_records=256,
                           value_dim=2, seed=4)
     ccfg = ClusterConfig(num_nodes=1, num_ranges=8, replication=1, r_max=1,
@@ -490,6 +491,46 @@ def test_dist_backend_single_device_mesh():
                       backend="dist", mesh=mesh)
     rows = drv.run()
     assert all(r.throughput > 0 for r in rows)
+
+
+@pytest.mark.parametrize("n_records,capacity", [
+    (512, None),    # a whole number of chunks
+    (1000, None),   # a padded tail chunk
+    (700, 64),      # slabs overflow: drops must match too
+])
+def test_chunked_preload_matches_one_batch(monkeypatch, n_records, capacity):
+    """The driver's preload, in chunks of 256 records, leaves the store
+    bit-identical to PUTting every record in one batch."""
+    from repro.cluster import epoch
+    from repro.core.store import apply_routed, make_store
+
+    monkeypatch.setattr(epoch, "PRELOAD_CHUNK", 256)
+
+    scfg = ScenarioConfig(n_epochs=2, epoch_ops=256, n_records=n_records,
+                          value_dim=4, seed=3)
+    ccfg = ClusterConfig(num_nodes=8, num_ranges=32, replication=3, r_max=4,
+                         capacity=capacity)
+    drv = EpochDriver(make_scenario("shifting_hotspot", scfg),
+                      make_policy("full_adaptive"), ccfg)
+    keys, vals = drv.scenario.load()
+    q = C.make_queries(jnp.asarray(keys), jnp.full((len(keys),), K.OP_PUT),
+                       jnp.asarray(vals))
+    ref, _ = apply_routed(
+        make_store(8, drv.store.capacity, 4), q, C.route(drv.directory, q)[0],
+        max_scan_results=ccfg.max_scan_results)
+    for f in ("keys", "values", "overflow"):
+        np.testing.assert_array_equal(np.asarray(getattr(drv.store, f)),
+                                      np.asarray(getattr(ref, f)), err_msg=f)
+    assert (int(np.asarray(ref.overflow).sum()) > 0) == (capacity is not None)
+
+
+def test_dist_backend_rejects_nodes_per_device_mismatch():
+    """The dist data plane holds one storage node per device; more nodes
+    than devices would run, with wrong migration accounting."""
+    scen = make_scenario("stationary", TINY_SCFG)
+    with pytest.raises(ValueError, match="one storage node per device"):
+        EpochDriver(scen, make_policy("frozen"), TINY_CCFG, backend="dist",
+                    mesh=make_mesh((1,)))
 
 
 def test_policy_registry():

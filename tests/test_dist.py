@@ -16,19 +16,11 @@ ENV = {**os.environ, "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
        "PYTHONPATH": os.path.join(os.path.dirname(__file__), "..", "src")}
 
 
-# jax < 0.5 has no jax.sharding.AxisType / make_mesh(axis_types=...)
-MESH_COMPAT = """
-import jax
-def compat_mesh(shape, names):
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is None:
-        return jax.make_mesh(shape, names)
-    return jax.make_mesh(shape, names, axis_types=(at.Auto,) * len(names))
-"""
+PRELUDE = "from repro.core.dist_store import make_mesh\n"
 
 
 def run_sub(code: str):
-    r = subprocess.run([sys.executable, "-c", MESH_COMPAT + textwrap.dedent(code)],
+    r = subprocess.run([sys.executable, "-c", PRELUDE + textwrap.dedent(code)],
                        env=ENV, capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
     return r.stdout
@@ -39,7 +31,7 @@ def test_dist_store_matches_oracle():
         import jax, jax.numpy as jnp, numpy as np
         from repro import core as C
 
-        mesh = compat_mesh((8,), ("data",))
+        mesh = make_mesh((8,))
         d = C.make_directory(16, 8, 3)
         store = C.make_store(8, 64, 4)
         rng = np.random.default_rng(0)
@@ -67,7 +59,7 @@ def test_dist_store_bucket_overflow_counted():
         import jax, jax.numpy as jnp, numpy as np
         from repro import core as C
 
-        mesh = compat_mesh((8,), ("data",))
+        mesh = make_mesh((8,))
         d = C.make_directory(16, 8, 1)
         store = C.make_store(8, 256, 1)
         # aim every query at one key -> one target shard; cap tiny -> overflow
@@ -88,7 +80,7 @@ def test_dist_store_read_spread_matches_tail_reads():
         import jax, jax.numpy as jnp, numpy as np
         from repro import core as C
 
-        mesh = compat_mesh((8,), ("data",))
+        mesh = make_mesh((8,))
         d = C.make_directory(16, 8, 3, r_max=5)
         store = C.make_store(8, 64, 4)
         rng = np.random.default_rng(0)
@@ -115,6 +107,39 @@ def test_dist_store_read_spread_matches_tail_reads():
     """)
 
 
+def test_dist_preload_is_shard_local_and_matches_oracle():
+    """The dist load phase writes each slab on its own device (the store
+    is made and stays sharded, one node per device) and loads the same
+    store as the oracle backend, in chunks that do not divide the record
+    count."""
+    run_sub("""
+        import numpy as np
+        import repro.cluster.epoch as E
+        from repro.cluster import (ClusterConfig, EpochDriver, ScenarioConfig,
+                                   make_policy, make_scenario)
+
+        E.PRELOAD_CHUNK = 300        # dist rounds it up to 304 (8 slices)
+        mesh = make_mesh((8,))
+        scfg = ScenarioConfig(n_epochs=1, epoch_ops=256, n_records=1001,
+                              value_dim=2, seed=3)
+        ccfg = ClusterConfig(num_nodes=8, num_ranges=32, replication=3,
+                             r_max=4)
+        drv = {b: EpochDriver(make_scenario("shifting_hotspot", scfg),
+                              make_policy("frozen"), ccfg, backend=b,
+                              mesh=mesh if b == "dist" else None)
+               for b in ("oracle", "dist")}
+        for f in ("keys", "values", "overflow"):
+            assert np.array_equal(np.asarray(getattr(drv["oracle"].store, f)),
+                                  np.asarray(getattr(drv["dist"].store, f))), f
+        assert int((np.asarray(drv["dist"].store.keys) != 2**32 - 1).sum()) >= 1001
+        for leaf in (drv["dist"].store.keys, drv["dist"].store.values):
+            shards = leaf.addressable_shards
+            assert len({s.device for s in shards}) == 8
+            assert {s.data.shape[0] for s in shards} == {1}
+        print("ok")
+    """)
+
+
 # Shared scaffold for the fused-dist ≡ per-epoch-dist parity tests: runs
 # the same scenario through the dist backend with fused=False / fused=True
 # and asserts every observable is bit-identical — the EpochMetrics stream,
@@ -129,7 +154,7 @@ from repro.cluster import (ClusterConfig, EpochDriver, ScenarioConfig,
 from repro.overload import OverloadConfig
 from repro.telemetry import TelemetryConfig
 
-mesh = compat_mesh((8,), ("data",))
+mesh = make_mesh((8,))
 scfg = ScenarioConfig(n_epochs=6, epoch_ops=256, n_records=512,
                       value_dim=2, seed=3)
 base = dict(num_nodes=8, num_ranges=32, replication=2, r_max=4,
@@ -264,7 +289,7 @@ def test_compressed_dp_train_step():
         from repro.training.optimizer import OptConfig
 
         cfg = get_config("qwen2-1.5b").reduced()
-        mesh = compat_mesh((8,), ("data",))
+        mesh = make_mesh((8,))
         tcfg = TrainConfig(opt=OptConfig(lr=1e-3, warmup_steps=2, total_steps=40),
                            remat=False, grad_compression=True, dp_axes=("data",))
         state = init_train_state(cfg, tcfg, jax.random.PRNGKey(0))
@@ -295,7 +320,7 @@ def test_sharded_train_step_lowers_on_2x4():
         from repro.launch.input_specs import batch_specs_for
 
         cfg = get_config("qwen2-1.5b").reduced()
-        mesh = compat_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         tcfg = TrainConfig(opt=OptConfig(), remat=True, microbatches=2)
         state = abstract_train_state(cfg, tcfg)
         shape = ShapeSpec("tiny", 64, 8, "train")
@@ -332,7 +357,7 @@ def test_real_sharded_execution_matches_single_device():
         # single-device reference
         s_ref, m_ref = jax.jit(step)(state, batch)
 
-        mesh = compat_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ssp = SH.state_specs(jax.eval_shape(lambda: state), mesh, dp_axes=("data",))
         bsp = SH.batch_specs(jax.eval_shape(lambda: batch), ("data",))
         j = jax.jit(step, in_shardings=(SH.to_named(ssp, mesh), SH.to_named(bsp, mesh)))

@@ -286,9 +286,9 @@ def test_dist_fused_bitident_vs_per_epoch():
     the period boundary, stacking plans/metrics on device — the stream
     must still match per-epoch dist stepping exactly (ordering of the
     stacked epochs, pull boundaries, overflow diffs)."""
-    import jax
+    from repro.core.dist_store import make_mesh
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,))
     scfg = ScenarioConfig(n_epochs=4, epoch_ops=128, n_records=256,
                           value_dim=2, seed=4)
     ccfg_kw = dict(num_nodes=1, num_ranges=8, replication=1, r_max=1,

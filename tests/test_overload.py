@@ -39,6 +39,7 @@ from repro.cluster import (
 from repro.cluster.policies import OverloadAdaptivePolicy, PolicyConfig
 from repro.core import keys as K
 from repro.core.coordination import plan_hops
+from repro.core.dist_store import make_mesh
 from repro.core.stats import StatsReport
 
 SCFG = ScenarioConfig(n_epochs=6, epoch_ops=256, n_records=512,
@@ -438,7 +439,7 @@ def test_split_overflow_grows_pool_on_dist_backend():
     themselves, so growth costs exactly one recompile there too
     (``traces == 1 + growth_events``) and the fused period program
     stays bit-identical to per-epoch stepping across the growth."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,))
     scfg = ScenarioConfig(n_epochs=10, epoch_ops=512, n_records=2048,
                           read_ratio=0.3, value_dim=2)
     out = {}

@@ -494,8 +494,9 @@ def test_dist_craq_write_broadcast_matches_single_host():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from repro import core as C
+        from repro.core.dist_store import make_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,))
         d = C.make_directory(16, 8, 3, r_max=5)
         store = C.make_store(8, 64, 4)
         rng0 = np.random.default_rng(0)

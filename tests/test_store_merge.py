@@ -18,7 +18,9 @@ import pytest
 
 from repro.core.store import (
     EMPTY,
+    _CUMSUM_BLOCK,
     _compact_sorted,
+    _cumsum,
     _dedupe_last_write,
     _member_sorted,
     make_store,
@@ -179,6 +181,20 @@ def test_compact_sorted_prefix_and_zero_tail():
     np.testing.assert_array_equal(np.asarray(v)[:3],
                                   [[0, 1], [4, 5], [8, 9]])
     assert (np.asarray(v)[3:] == 0).all()
+
+
+@pytest.mark.parametrize("n", [
+    1, _CUMSUM_BLOCK, _CUMSUM_BLOCK + 1, 3 * _CUMSUM_BLOCK, 5000,
+])
+def test_blocked_cumsum_matches_cumsum(n):
+    x = np.random.default_rng(n).integers(0, 3, n).astype(np.int32)
+    got = _cumsum(jnp.asarray(x))
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.cumsum(x))
+    # under lax.map, as apply_routed runs the slab primitives per shard
+    xs = np.stack([x, x[::-1]])
+    np.testing.assert_array_equal(np.asarray(jax.lax.map(_cumsum, xs)),
+                                  np.cumsum(xs, axis=1))
 
 
 def test_dedupe_last_write_zeroes_dead_slots():

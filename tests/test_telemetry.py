@@ -23,6 +23,7 @@ Pins the tentpole contracts:
 import dataclasses
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -40,6 +41,7 @@ from repro.cluster import (
 )
 from repro.cluster.policies import PolicyConfig
 from repro.core.coordination import LatencyModel
+from repro.launch import mesh as MESH
 from repro.overload import OverloadConfig
 from repro.telemetry import (
     BUCKETS,
@@ -430,12 +432,17 @@ def test_driver_stage_timers_fire(traced_run):
     assert "stage_share" in drv.telemetry.summary()
 
 
-def test_kernel_roofline_rows_smoke():
+def test_kernel_roofline_rows_smoke(monkeypatch):
+    # give the running device (the CPU here) a peak table of its own, so
+    # the rows are priced against the device that ran them
+    kind = jax.devices()[0].device_kind
+    monkeypatch.setitem(MESH.PEAKS, kind, MESH.PEAKS["TPU v5 lite"])
     rows = kernel_roofline_rows(batch=256, num_ranges=16, num_nodes=4,
                                 measure_iters=1)
     assert [r["kernel"] for r in rows] == list(KERNELS)
     for r in rows:
         assert r["impl"] == "ref"
+        assert r["device_kind"] == kind
         assert r["bytes"] > 0
         # the routing kernels are integer-hash/compare/select lookups:
         # no FP work, so they sit flat on the memory roof
@@ -445,6 +452,15 @@ def test_kernel_roofline_rows_smoke():
         assert r["measured_us"] > 0
         assert r["intensity_flop_per_byte"] == pytest.approx(
             r["flops"] / r["bytes"])
+
+
+def test_kernel_roofline_rejects_unknown_device_kind():
+    """Peaks are keyed by device kind: a device with no entry (the CPU
+    here) is an error, never silently priced as a v5e."""
+    assert jax.devices()[0].device_kind not in MESH.PEAKS
+    with pytest.raises(ValueError, match="no peak table"):
+        kernel_roofline_rows(batch=256, num_ranges=16, num_nodes=4,
+                             measure_iters=1)
 
 
 # ---------------------------------------------------------------------------
