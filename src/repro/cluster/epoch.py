@@ -54,6 +54,15 @@ reserves both kinds of headroom), so the period scan traces **once per
 scenario** — asserted via :attr:`EpochDriver.traces` (the jit cache
 size, which also catches dist-backend retraces) in tests and recorded
 per bench row.
+
+What the device step does is visible from outside it.  Its stages run
+under the named scopes of :data:`PERIOD_SCOPES` (``route``, ``apply``
+with the second-level :data:`APPLY_SCOPES`, ``observe``, ``commit``),
+and :meth:`EpochDriver.period_op_scopes` maps each op of the compiled
+period program to its stage.  Each epoch returns the digest of the GET
+replies it served (``EpochMetrics.get_digest``) and counts the slab rows
+it rewrote and the PUT rows it applied, which enabled stage timers add
+up (``StageTimers.counts``).
 """
 
 from __future__ import annotations
@@ -78,7 +87,15 @@ from repro.core.dist_store import (
 )
 from repro.core.migration import execute as execute_migrations
 from repro.core.stats import make_sketch, pull_report, sketch_query, sketch_update
-from repro.core.store import apply_routed, make_store
+from repro.core import store as S
+from repro.core.store import (
+    APPLY_SCOPES,
+    PERIOD_SCOPES,
+    apply_routed,
+    apply_routed_counted,
+    get_digest,
+    make_store,
+)
 from repro import coordination_tier as CT
 from repro import overload as OVL
 from repro import replication as RPL
@@ -273,6 +290,13 @@ class EpochDriver:
     ``lax.scan`` (oracle) or one deferred-sync step loop (dist) with a
     single host round-trip per period; ``fused=False`` is the per-epoch
     reference loop the fused pipeline is asserted bit-identical against.
+
+    ``timers`` is the :class:`~repro.telemetry.StageTimers` the driver
+    times its stages and counts the store's work with (default: the span
+    plane's when ``cfg.telemetry`` is set, else disabled ones).  Enabled,
+    each segment also adds the slab rows its period rewrote
+    (``slab_rows_rewritten``) and the PUT rows it applied at chain
+    members (``put_rows``) to ``timers.counts``.
     """
 
     def __init__(
@@ -285,6 +309,7 @@ class EpochDriver:
         mesh=None,
         dist_cfg: DistConfig | None = None,
         fused: bool = True,
+        timers: TEL.StageTimers | None = None,
     ):
         self.scenario = scenario
         self.policy = policy
@@ -438,11 +463,14 @@ class EpochDriver:
                 self.tel_cfg, model=cfg.latency, scenario=scenario.name,
                 policy=policy.name, n_clients=cfg.n_clients,
             )
-            self._timers = self.telemetry.timers
         else:
             self._tel_threshold = 0
             self.telemetry = None
-            self._timers = TEL.StageTimers(enabled=False)
+        # the stage timers: the caller's, else the span plane's, else off
+        if timers is None:
+            timers = (self.telemetry.timers if self.telemetry is not None
+                      else TEL.StageTimers(enabled=False))
+        self._timers = timers
         # the fleet metrics plane: a (window, n_series) f32 ring carried
         # (and donated) through the fused scan; None == empty pytree
         # slot, the same discipline as the overload/coordination planes
@@ -504,6 +532,7 @@ class EpochDriver:
         self._mesh = mesh
         self._step = None
         self._period_fn = None
+        self._period_inputs = None
         if backend == "dist":
             base = dist_cfg or DistConfig()
             self._dist_cfg = dataclasses.replace(
@@ -678,160 +707,171 @@ class EpochDriver:
                 r_ovl = jax.random.fold_in(rng, 0x0F10AD)
             r_route, r_plan = jax.random.split(rng)
             B = q.opcode.shape[0]
-            # deep queues repel p2c reads: the pre-epoch queue depth joins
-            # the load registers in the pick comparison (registers still
-            # bump raw, and the kernels fold the same penalty at the ops
-            # layer — parity by construction)
-            queue_pen = None
-            if ocfg is not None and ocfg.queue_weight > 0 and spread:
-                queue_pen = ovl.queue.astype(jnp.uint32) * jnp.uint32(
-                    ocfg.queue_weight
-                )
-            # reads consult the PRE-epoch dirty state, exactly as they
-            # observe the pre-batch store (repro.replication.state)
-            dirty = RPL.dirty_bits(repl) if mp.dirty_reads else None
-            kf = (repl.key_filter
-                  if (mp.dirty_reads and fbits) else None)
-            if spread and chunks > 1:
-                csize = B // chunks
-                decs, picks, bncs = [], [], []
-                for ci in range(chunks):
-                    qs = jax.tree.map(
-                        lambda x: x[ci * csize : (ci + 1) * csize], q
+            with jax.named_scope(S.ROUTE):
+                # deep queues repel p2c reads: the pre-epoch queue depth
+                # joins the load registers in the pick comparison
+                # (registers still bump raw, and the kernels fold the same
+                # penalty at the ops layer — parity by construction)
+                queue_pen = None
+                if ocfg is not None and ocfg.queue_weight > 0 and spread:
+                    queue_pen = ovl.queue.astype(jnp.uint32) * jnp.uint32(
+                        ocfg.queue_weight
                     )
-                    dec, directory, load_reg, picked, bounced = route_chunk(
-                        directory, load_reg, dirty, kf, qs,
-                        jax.random.fold_in(r_route, ci), queue_pen,
+                # reads consult the PRE-epoch dirty state, exactly as they
+                # observe the pre-batch store (repro.replication.state)
+                dirty = RPL.dirty_bits(repl) if mp.dirty_reads else None
+                kf = (repl.key_filter
+                      if (mp.dirty_reads and fbits) else None)
+                if spread and chunks > 1:
+                    csize = B // chunks
+                    decs, picks, bncs = [], [], []
+                    for ci in range(chunks):
+                        qs = jax.tree.map(
+                            lambda x: x[ci * csize : (ci + 1) * csize], q
+                        )
+                        (dec, directory, load_reg, picked,
+                         bounced) = route_chunk(
+                            directory, load_reg, dirty, kf, qs,
+                            jax.random.fold_in(r_route, ci), queue_pen,
+                        )
+                        decs.append(dec)
+                        picks.append(picked)
+                        bncs.append(bounced)
+                    decision = jax.tree.map(
+                        lambda *xs: jnp.concatenate(xs, axis=0), *decs
                     )
-                    decs.append(dec)
-                    picks.append(picked)
-                    bncs.append(bounced)
-                decision = jax.tree.map(
-                    lambda *xs: jnp.concatenate(xs, axis=0), *decs
-                )
-                if mp.dirty_reads:
-                    picked = jnp.concatenate(picks, axis=0)
-                    bounced = jnp.concatenate(bncs, axis=0)
-            else:
-                decision, directory, load_reg, picked, bounced = route_chunk(
-                    directory, load_reg, dirty, kf, q, r_route, queue_pen
-                )
-            node_ops = _node_ops(decision, q.opcode, N)
-            if not spread:
-                # tail-read path: registers tracked for parity (same units)
-                load_reg = load_reg + node_ops.astype(jnp.uint32)
-            sketch = sketch_update(sketch, q.key)
-            store, resp = apply_routed(
-                store, q, decision, max_scan_results=cfg.max_scan_results
-            )
-            bounce_kw = (
-                dict(read_via=picked, read_bounce=bounced)
-                if mp.dirty_reads else {}
-            )
-            # overload step: queue/retry dynamics decide each query's
-            # timing fate (the store above applied every op regardless —
-            # accounting plane, see repro.overload).  The pre-step state
-            # is the admission context the trace plane records: queue
-            # depth at entry, exactly as routing observes the pre-epoch
-            # store
-            ovl_pre = ovl
-            if ocfg is not None:
-                ovl, ovl_rej, ovl_scale, ovl_out, ostats = OVL.step(
-                    ovl, decision.target, r_ovl, ocfg
-                )
-                ovl_kw = dict(shed=ovl_rej, service_scale=ovl_scale)
-                # cross-epoch retry linking: stamp/clear the hashed
-                # orbit-identity register (no-op at the (1,) placeholder)
-                ovl, first_epoch = OVL.link_orbit(
-                    ovl, q.key, ovl_rej,
-                    ovl_out == OVL.OUTCOME_ADMITTED, eid,
-                )
-            else:
-                ostats = jnp.zeros((len(OVL.STAT_FIELDS),), jnp.int32)
-                ovl_kw = {}
-                first_epoch = None
-            # the switch tier observes the batch against its (possibly
-            # stale) per-switch table copies: versioned-redirect decision,
-            # install of pending control writes, conservation counters.
-            # Pure accounting — the decision above (and every store/
-            # counter/PRNG effect) followed the TRUE tables, so the tier
-            # only reprices hops and emits cstats
-            if ccfg is not None:
-                coord, redirect, redirect_via, cstats = CT.observe_epoch(
-                    coord, q, decision, eid, quorum=ccfg.quorum,
-                    hash_partitioned=hp,
-                )
-                coord_kw = dict(redirect=redirect,
-                                redirect_via=redirect_via)
-            else:
-                redirect = None
-                cstats = CT.empty_cstats()
-                coord_kw = {}
-            plan = plan_hops(
-                q, decision, cfg.mode, cfg.latency, rng=r_plan, num_nodes=N,
-                write_chain_cap=cap, service_model=cfg.service_model,
-                **bounce_kw, **ovl_kw, **coord_kw,
-            )
-            if mp.track_state:
-                is_write = (q.opcode == K.OP_PUT) | (q.opcode == K.OP_DEL)
-                repl = RPL.advance(repl, decision.ridx, is_write,
-                                   keys=q.key if fbits else None)
-            retries = jnp.zeros((), jnp.int32)
-            bounced_out = (bounced if mp.dirty_reads
-                           else jnp.zeros((B,), jnp.bool_))
-            # span attribution only: a versioned redirect rides the bounce
-            # bucket of the trace plane (an extra pre-serve hop), while the
-            # metric-stream bounced column stays CRAQ-only for parity
-            span_bounced = (bounced_out if redirect is None
-                            else bounced_out | redirect)
-            if tcfg is not None:
-                if ocfg is not None:
-                    t_safe = jnp.clip(decision.target, 0, N - 1)
-                    qdepth = ovl_pre.queue[t_safe]
-                    Lv = ovl_pre.retry.shape[1]
-                    # deepest occupied backoff level at the target (how
-                    # far its retry orbit has escalated); -1 when empty
-                    orbit_node = jnp.max(
-                        jnp.where(
-                            ovl_pre.retry > 0,
-                            jnp.arange(1, Lv + 1, dtype=jnp.int32)[None, :],
-                            0,
-                        ),
-                        axis=1,
-                    ) - 1
-                    orbit = orbit_node[t_safe]
-                    outcome = ovl_out
-                    scale_rec = ovl_scale
+                    if mp.dirty_reads:
+                        picked = jnp.concatenate(picks, axis=0)
+                        bounced = jnp.concatenate(bncs, axis=0)
                 else:
-                    qdepth = jnp.zeros((B,), jnp.int32)
-                    orbit = jnp.full((B,), -1, jnp.int32)
-                    outcome = jnp.where(
-                        decision.target >= 0,
-                        jnp.int32(OVL.OUTCOME_ADMITTED),
-                        jnp.int32(OVL.OUTCOME_INVALID),
+                    (decision, directory, load_reg, picked,
+                     bounced) = route_chunk(
+                        directory, load_reg, dirty, kf, q, r_route, queue_pen
                     )
-                    scale_rec = jnp.ones((B,), jnp.float32)
-                pk = picked if mp.dirty_reads else decision.target
-                spans = TEL.collect_spans(
-                    q, eid, decision, pk, span_bounced, outcome, qdepth,
-                    orbit, scale_rec, plan,
-                    threshold=tel_thr, k_slots=tcfg.max_spans,
-                    lookup=cfg.latency.lookup, first_epoch=first_epoch,
+            with jax.named_scope(S.APPLY):
+                node_ops = _node_ops(decision, q.opcode, N)
+                if not spread:
+                    # tail-read path: registers tracked for parity (same
+                    # units)
+                    load_reg = load_reg + node_ops.astype(jnp.uint32)
+            with jax.named_scope(S.OBSERVE):
+                sketch = sketch_update(sketch, q.key)
+            with jax.named_scope(S.APPLY):
+                store, resp, counts = apply_routed_counted(
+                    store, q, decision, max_scan_results=cfg.max_scan_results
                 )
-            else:
-                spans = None
-            if mcfg is not None:
-                # the fleet metrics row: post-step ovl, post-observe
-                # coord, post-advance repl — end-of-epoch state, like
-                # the flight ring's snapshots.  Pure observer.
-                metrics = MTR.record_epoch(
-                    metrics, node_ops=node_ops, ovl=ovl, ostats=ostats,
-                    cstats=cstats, coord=coord, repl=repl, sketch=sketch,
-                    keys=q.key, ridx=decision.ridx, topk=met_topk,
+                with jax.named_scope(S.GET):
+                    digest = get_digest(q.opcode, q.key, resp.value,
+                                        resp.found)
+            with jax.named_scope(S.OBSERVE):
+                bounce_kw = (
+                    dict(read_via=picked, read_bounce=bounced)
+                    if mp.dirty_reads else {}
                 )
+                # overload step: queue/retry dynamics decide each query's
+                # timing fate (the store above applied every op regardless —
+                # accounting plane, see repro.overload).  The pre-step state
+                # is the admission context the trace plane records: queue
+                # depth at entry, exactly as routing observes the pre-epoch
+                # store
+                ovl_pre = ovl
+                if ocfg is not None:
+                    ovl, ovl_rej, ovl_scale, ovl_out, ostats = OVL.step(
+                        ovl, decision.target, r_ovl, ocfg
+                    )
+                    ovl_kw = dict(shed=ovl_rej, service_scale=ovl_scale)
+                    # cross-epoch retry linking: stamp/clear the hashed
+                    # orbit-identity register (no-op at the (1,) placeholder)
+                    ovl, first_epoch = OVL.link_orbit(
+                        ovl, q.key, ovl_rej,
+                        ovl_out == OVL.OUTCOME_ADMITTED, eid,
+                    )
+                else:
+                    ostats = jnp.zeros((len(OVL.STAT_FIELDS),), jnp.int32)
+                    ovl_kw = {}
+                    first_epoch = None
+                # the switch tier observes the batch against its (possibly
+                # stale) per-switch table copies: versioned-redirect decision,
+                # install of pending control writes, conservation counters.
+                # Pure accounting — the decision above (and every store/
+                # counter/PRNG effect) followed the TRUE tables, so the tier
+                # only reprices hops and emits cstats
+                if ccfg is not None:
+                    coord, redirect, redirect_via, cstats = CT.observe_epoch(
+                        coord, q, decision, eid, quorum=ccfg.quorum,
+                        hash_partitioned=hp,
+                    )
+                    coord_kw = dict(redirect=redirect,
+                                    redirect_via=redirect_via)
+                else:
+                    redirect = None
+                    cstats = CT.empty_cstats()
+                    coord_kw = {}
+                plan = plan_hops(
+                    q, decision, cfg.mode, cfg.latency, rng=r_plan, num_nodes=N,
+                    write_chain_cap=cap, service_model=cfg.service_model,
+                    **bounce_kw, **ovl_kw, **coord_kw,
+                )
+                if mp.track_state:
+                    is_write = (q.opcode == K.OP_PUT) | (q.opcode == K.OP_DEL)
+                    repl = RPL.advance(repl, decision.ridx, is_write,
+                                       keys=q.key if fbits else None)
+                retries = jnp.zeros((), jnp.int32)
+                bounced_out = (bounced if mp.dirty_reads
+                               else jnp.zeros((B,), jnp.bool_))
+                # span attribution only: a versioned redirect rides the bounce
+                # bucket of the trace plane (an extra pre-serve hop), while the
+                # metric-stream bounced column stays CRAQ-only for parity
+                span_bounced = (bounced_out if redirect is None
+                                else bounced_out | redirect)
+                if tcfg is not None:
+                    if ocfg is not None:
+                        t_safe = jnp.clip(decision.target, 0, N - 1)
+                        qdepth = ovl_pre.queue[t_safe]
+                        Lv = ovl_pre.retry.shape[1]
+                        # deepest occupied backoff level at the target (how
+                        # far its retry orbit has escalated); -1 when empty
+                        orbit_node = jnp.max(
+                            jnp.where(
+                                ovl_pre.retry > 0,
+                                jnp.arange(1, Lv + 1, dtype=jnp.int32)[None, :],
+                                0,
+                            ),
+                            axis=1,
+                        ) - 1
+                        orbit = orbit_node[t_safe]
+                        outcome = ovl_out
+                        scale_rec = ovl_scale
+                    else:
+                        qdepth = jnp.zeros((B,), jnp.int32)
+                        orbit = jnp.full((B,), -1, jnp.int32)
+                        outcome = jnp.where(
+                            decision.target >= 0,
+                            jnp.int32(OVL.OUTCOME_ADMITTED),
+                            jnp.int32(OVL.OUTCOME_INVALID),
+                        )
+                        scale_rec = jnp.ones((B,), jnp.float32)
+                    pk = picked if mp.dirty_reads else decision.target
+                    spans = TEL.collect_spans(
+                        q, eid, decision, pk, span_bounced, outcome, qdepth,
+                        orbit, scale_rec, plan,
+                        threshold=tel_thr, k_slots=tcfg.max_spans,
+                        lookup=cfg.latency.lookup, first_epoch=first_epoch,
+                    )
+                else:
+                    spans = None
+                if mcfg is not None:
+                    # the fleet metrics row: post-step ovl, post-observe
+                    # coord, post-advance repl — end-of-epoch state, like
+                    # the flight ring's snapshots.  Pure observer.
+                    metrics = MTR.record_epoch(
+                        metrics, node_ops=node_ops, ovl=ovl, ostats=ostats,
+                        cstats=cstats, coord=coord, repl=repl, sketch=sketch,
+                        keys=q.key, ridx=decision.ridx, topk=met_topk,
+                    )
             return (store, directory, load_reg, sketch, repl, ovl, coord,
                     metrics, plan, node_ops, retries, bounced_out, ostats,
-                    cstats, spans)
+                    cstats, spans, digest, counts)
 
         return body
 
@@ -861,30 +901,33 @@ class EpochDriver:
 
         def period(store, directory, load_reg, sketch, repl, ovl, coord,
                    metrics, qs, rngs, live, eids):
+            self._note_period_inputs(qs, rngs, live, eids)
+
             def scan_body(carry, xs):
                 (store, directory, load_reg, sketch, repl, ovl, coord,
                  metrics) = carry
                 q, rng, lv, eid = xs
                 (store2, directory2, load_reg2, sketch2, repl2, ovl2,
                  coord2, metrics2, plan, node_ops, retries, bounced,
-                 ostats, cstats, spans) = body(
+                 ostats, cstats, spans, digest, counts) = body(
                     store, directory, load_reg, sketch, repl, ovl, coord,
                     metrics, q, rng, eid
                 )
-                keep = lambda new, old: jnp.where(lv, new, old)
-                store2 = jax.tree.map(keep, store2, store)
-                directory2 = jax.tree.map(keep, directory2, directory)
-                carry2 = (store2, directory2, keep(load_reg2, load_reg),
-                          keep(sketch2, sketch),
-                          jax.tree.map(keep, repl2, repl),
-                          jax.tree.map(keep, ovl2, ovl),
-                          jax.tree.map(keep, coord2, coord),
-                          jax.tree.map(keep, metrics2, metrics))
-                ovf = jnp.sum(store2.overflow)
+                with jax.named_scope(S.COMMIT):
+                    keep = lambda new, old: jnp.where(lv, new, old)
+                    store2 = jax.tree.map(keep, store2, store)
+                    directory2 = jax.tree.map(keep, directory2, directory)
+                    carry2 = (store2, directory2, keep(load_reg2, load_reg),
+                              keep(sketch2, sketch),
+                              jax.tree.map(keep, repl2, repl),
+                              jax.tree.map(keep, ovl2, ovl),
+                              jax.tree.map(keep, coord2, coord),
+                              jax.tree.map(keep, metrics2, metrics))
+                    ovf = jnp.sum(store2.overflow)
                 # spans ride the ys stack (None == empty pytree when the
                 # trace plane is off — the program is unchanged)
                 return carry2, (plan, node_ops, retries, ovf, bounced,
-                                ostats, cstats, spans)
+                                ostats, cstats, spans, digest, counts)
 
             carry, outs = jax.lax.scan(
                 scan_body,
@@ -927,103 +970,104 @@ class EpochDriver:
         def observe(q, ridx, target, chain, chain_len, sketch, rng, repl,
                     picked, bounced, ovl, r_ovl, eid, coord, metrics):
             """Post-processing of the dist apply's decision."""
-            B = target.shape[0]
-            decision = C.RoutingDecision(
-                ridx=ridx,
-                target=target,
-                chain=chain,
-                chain_len=chain_len,
-                clength=jnp.zeros_like(target),
-            )
-            node_ops = _node_ops(decision, q.opcode, N)
-            sketch = sketch_update(sketch, q.key)
-            bounce_kw = (dict(read_via=picked, read_bounce=bounced)
-                         if mp.dirty_reads else {})
-            # overload step: same accounting-plane placement as the oracle
-            # body — after the distributed apply, deciding timing fate only
-            ovl_pre = ovl
-            if ocfg is not None:
-                ovl, ovl_rej, ovl_scale, ovl_out, ostats = OVL.step(
-                    ovl, target, r_ovl, ocfg
+            with jax.named_scope(S.OBSERVE):
+                B = target.shape[0]
+                decision = C.RoutingDecision(
+                    ridx=ridx,
+                    target=target,
+                    chain=chain,
+                    chain_len=chain_len,
+                    clength=jnp.zeros_like(target),
                 )
-                ovl_kw = dict(shed=ovl_rej, service_scale=ovl_scale)
-                ovl, first_epoch = OVL.link_orbit(
-                    ovl, q.key, ovl_rej,
-                    ovl_out == OVL.OUTCOME_ADMITTED, eid,
-                )
-            else:
-                ostats = jnp.zeros((len(OVL.STAT_FIELDS),), jnp.int32)
-                ovl_kw = {}
-                first_epoch = None
-            # the coordination tier observes the global batch (same
-            # accounting-plane placement as the oracle body: redirects
-            # reprice hops, nothing else changes)
-            if ccfg is not None:
-                coord, redirect, redirect_via, cstats = CT.observe_epoch(
-                    coord, q, decision, eid, quorum=ccfg.quorum,
-                    hash_partitioned=hp,
-                )
-                coord_kw = dict(redirect=redirect,
-                                redirect_via=redirect_via)
-            else:
-                redirect = None
-                cstats = CT.empty_cstats()
-                coord_kw = {}
-            plan = plan_hops(
-                q, decision, cfg.mode, cfg.latency, rng=rng, num_nodes=N,
-                write_chain_cap=mp.write_cap_spread,
-                service_model=cfg.service_model, **bounce_kw, **ovl_kw,
-                **coord_kw,
-            )
-            if mp.track_state:
-                is_write = (q.opcode == K.OP_PUT) | (q.opcode == K.OP_DEL)
-                repl = RPL.advance(repl, ridx, is_write)
-            span_bounced = (bounced if redirect is None
-                            else bounced | redirect)
-            if tcfg is not None:
+                node_ops = _node_ops(decision, q.opcode, N)
+                sketch = sketch_update(sketch, q.key)
+                bounce_kw = (dict(read_via=picked, read_bounce=bounced)
+                             if mp.dirty_reads else {})
+                # overload step: same accounting-plane placement as the oracle
+                # body — after the distributed apply, deciding timing fate only
+                ovl_pre = ovl
                 if ocfg is not None:
-                    t_safe = jnp.clip(target, 0, N - 1)
-                    qdepth = ovl_pre.queue[t_safe]
-                    Lv = ovl_pre.retry.shape[1]
-                    orbit_node = jnp.max(
-                        jnp.where(
-                            ovl_pre.retry > 0,
-                            jnp.arange(1, Lv + 1, dtype=jnp.int32)[None, :],
-                            0,
-                        ),
-                        axis=1,
-                    ) - 1
-                    orbit = orbit_node[t_safe]
-                    outcome = ovl_out
-                    scale_rec = ovl_scale
-                else:
-                    qdepth = jnp.zeros((B,), jnp.int32)
-                    orbit = jnp.full((B,), -1, jnp.int32)
-                    outcome = jnp.where(
-                        target >= 0,
-                        jnp.int32(OVL.OUTCOME_ADMITTED),
-                        jnp.int32(OVL.OUTCOME_INVALID),
+                    ovl, ovl_rej, ovl_scale, ovl_out, ostats = OVL.step(
+                        ovl, target, r_ovl, ocfg
                     )
-                    scale_rec = jnp.ones((B,), jnp.float32)
-                spans = TEL.collect_spans(
-                    q, eid, decision, picked, span_bounced, outcome, qdepth,
-                    orbit, scale_rec, plan,
-                    threshold=tel_thr, k_slots=tcfg.max_spans,
-                    lookup=cfg.latency.lookup, first_epoch=first_epoch,
+                    ovl_kw = dict(shed=ovl_rej, service_scale=ovl_scale)
+                    ovl, first_epoch = OVL.link_orbit(
+                        ovl, q.key, ovl_rej,
+                        ovl_out == OVL.OUTCOME_ADMITTED, eid,
+                    )
+                else:
+                    ostats = jnp.zeros((len(OVL.STAT_FIELDS),), jnp.int32)
+                    ovl_kw = {}
+                    first_epoch = None
+                # the coordination tier observes the global batch (same
+                # accounting-plane placement as the oracle body: redirects
+                # reprice hops, nothing else changes)
+                if ccfg is not None:
+                    coord, redirect, redirect_via, cstats = CT.observe_epoch(
+                        coord, q, decision, eid, quorum=ccfg.quorum,
+                        hash_partitioned=hp,
+                    )
+                    coord_kw = dict(redirect=redirect,
+                                    redirect_via=redirect_via)
+                else:
+                    redirect = None
+                    cstats = CT.empty_cstats()
+                    coord_kw = {}
+                plan = plan_hops(
+                    q, decision, cfg.mode, cfg.latency, rng=rng, num_nodes=N,
+                    write_chain_cap=mp.write_cap_spread,
+                    service_model=cfg.service_model, **bounce_kw, **ovl_kw,
+                    **coord_kw,
                 )
-            else:
-                spans = None
-            if mcfg is not None:
-                # same end-of-epoch placement as the oracle body — the
-                # observe stage runs replicated on the global batch, so
-                # the ring row is identical on every device
-                metrics = MTR.record_epoch(
-                    metrics, node_ops=node_ops, ovl=ovl, ostats=ostats,
-                    cstats=cstats, coord=coord, repl=repl, sketch=sketch,
-                    keys=q.key, ridx=ridx, topk=met_topk,
-                )
-            return (sketch, plan, node_ops, repl, ovl, coord, metrics,
-                    ostats, cstats, spans)
+                if mp.track_state:
+                    is_write = (q.opcode == K.OP_PUT) | (q.opcode == K.OP_DEL)
+                    repl = RPL.advance(repl, ridx, is_write)
+                span_bounced = (bounced if redirect is None
+                                else bounced | redirect)
+                if tcfg is not None:
+                    if ocfg is not None:
+                        t_safe = jnp.clip(target, 0, N - 1)
+                        qdepth = ovl_pre.queue[t_safe]
+                        Lv = ovl_pre.retry.shape[1]
+                        orbit_node = jnp.max(
+                            jnp.where(
+                                ovl_pre.retry > 0,
+                                jnp.arange(1, Lv + 1, dtype=jnp.int32)[None, :],
+                                0,
+                            ),
+                            axis=1,
+                        ) - 1
+                        orbit = orbit_node[t_safe]
+                        outcome = ovl_out
+                        scale_rec = ovl_scale
+                    else:
+                        qdepth = jnp.zeros((B,), jnp.int32)
+                        orbit = jnp.full((B,), -1, jnp.int32)
+                        outcome = jnp.where(
+                            target >= 0,
+                            jnp.int32(OVL.OUTCOME_ADMITTED),
+                            jnp.int32(OVL.OUTCOME_INVALID),
+                        )
+                        scale_rec = jnp.ones((B,), jnp.float32)
+                    spans = TEL.collect_spans(
+                        q, eid, decision, picked, span_bounced, outcome, qdepth,
+                        orbit, scale_rec, plan,
+                        threshold=tel_thr, k_slots=tcfg.max_spans,
+                        lookup=cfg.latency.lookup, first_epoch=first_epoch,
+                    )
+                else:
+                    spans = None
+                if mcfg is not None:
+                    # same end-of-epoch placement as the oracle body — the
+                    # observe stage runs replicated on the global batch, so
+                    # the ring row is identical on every device
+                    metrics = MTR.record_epoch(
+                        metrics, node_ops=node_ops, ovl=ovl, ostats=ostats,
+                        cstats=cstats, coord=coord, repl=repl, sketch=sketch,
+                        keys=q.key, ridx=ridx, topk=met_topk,
+                    )
+                return (sketch, plan, node_ops, repl, ovl, coord, metrics,
+                        ostats, cstats, spans)
 
         return observe
 
@@ -1052,6 +1096,8 @@ class EpochDriver:
             return observe_body(*args)
 
         observe = jax.jit(observe)
+        digest_of = jax.jit(lambda q, resp: get_digest(
+            q.opcode, q.key, resp.value, resp.found))
 
         def step(store, directory, load_reg, sketch, repl, ovl, coord,
                  metrics, q, rng, eid):
@@ -1079,17 +1125,17 @@ class EpochDriver:
                 ),)
             if mp.dirty_reads:
                 dirty = jax.device_put(RPL.dirty_bits(repl), rep)
-                store, _resp, directory, load_reg, m = dist_apply(
+                store, resp, directory, load_reg, m = dist_apply(
                     store, directory, load_reg, *qp, dirty, q, r_route
                 )
                 picked, bounced = m["picked"], m["bounced"]
             elif spread:
-                store, _resp, directory, load_reg, m = dist_apply(
+                store, resp, directory, load_reg, m = dist_apply(
                     store, directory, load_reg, *qp, q, r_route
                 )
                 picked = bounced = None
             else:
-                store, _resp, directory, m = dist_apply(store, directory, q)
+                store, resp, directory, m = dist_apply(store, directory, q)
                 picked = bounced = None
             if picked is None:
                 # placeholders keep observe's signature mode-independent
@@ -1103,9 +1149,10 @@ class EpochDriver:
             )
             if not spread:
                 load_reg = load_reg + node_ops.astype(jnp.uint32)
+            counts = S.ApplyCounts(**{f: m[f] for f in S.ApplyCounts._fields})
             return (store, directory, load_reg, sketch, repl, ovl, coord,
                     metrics, plan, node_ops, m["bucket_overflow"], bounced,
-                    ostats, cstats, spans)
+                    ostats, cstats, spans, digest_of(q, resp), counts)
 
         return step
 
@@ -1139,37 +1186,73 @@ class EpochDriver:
         self._dist_period = make_dist_period(
             self._mesh, self.directory, self._dist_cfg,
             pre=pre, observe=self._make_dist_observe(),
-            fold_ovl=ocfg is not None,
+            fold_ovl=ocfg is not None, on_trace=self._note_period_inputs,
         )
         rep = NamedSharding(self._mesh, PartitionSpec())
         shd = NamedSharding(self._mesh, PartitionSpec(self._dist_cfg.axis))
 
+        def commit(store, *state):
+            return (jax.device_put(store, shd),
+                    *(jax.device_put(x, rep) for x in state))
+
         def period(store, directory, load_reg, sketch, repl, ovl, coord,
                    metrics, qs, rngs, live, eids):
-            store = jax.device_put(store, shd)
-            directory = jax.device_put(directory, rep)
-            load_reg = jax.device_put(load_reg, rep)
-            sketch = jax.device_put(sketch, rep)
-            repl = jax.device_put(repl, rep)
-            if ovl is not None:
-                ovl = jax.device_put(ovl, rep)
-            if coord is not None:
-                coord = jax.device_put(coord, rep)
-            if metrics is not None:
-                metrics = jax.device_put(metrics, rep)
             return self._dist_period(
-                store, directory, load_reg, sketch, repl, ovl, coord,
-                metrics, qs, rngs, live, eids,
+                *commit(store, directory, load_reg, sketch, repl, ovl,
+                        coord, metrics),
+                qs, rngs, live, eids,
             )
 
+        self._dist_commit = commit
         return period
 
     # -- host-side helpers -------------------------------------------------
-    def _sync(self, x) -> np.ndarray:
-        """Device->host transfer with bookkeeping (the profile metric the
-        fused pipeline exists to minimize)."""
+    def _sync(self, x):
+        """Device->host transfer of an array or a pytree of arrays, in one
+        round trip, with bookkeeping (the profile metric the fused
+        pipeline exists to minimize)."""
         self.host_syncs += 1
-        return np.asarray(x)
+        return jax.tree.map(np.asarray, jax.device_get(x))
+
+    def _pull_apply(self, ovf, digest, counts):
+        """The overflow totals and GET digests of a segment's epochs, in
+        one round trip; with the timers on, the segment's
+        :class:`~repro.core.store.ApplyCounts` ride along into
+        ``timers.counts``."""
+        if not self._timers.enabled:
+            ovf_h, digest_h = self._sync((ovf, digest))
+        else:
+            ovf_h, digest_h, counts_h = self._sync((ovf, digest, counts))
+            for name, n in counts_h._asdict().items():
+                self._timers.count(name, int(n.astype(np.int64).sum()))
+        return ovf_h.astype(np.int64), digest_h
+
+    def _note_period_inputs(self, qs, rngs, live, eids) -> None:
+        """Trace-time hook of the period program: keep the shapes of its
+        staged inputs, which :meth:`period_op_scopes` lowers it with."""
+        self._period_inputs = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+            (qs, rngs, live, eids))
+
+    def period_op_scopes(self) -> TEL.OpScopes:
+        """The named scope of every operation of the period program as
+        compiled for the driver's current shapes (see
+        :func:`repro.telemetry.profiler.hlo_op_scopes`), read from the
+        compiled program's text.  The program must have run: its compiled
+        executable is reused, nothing is compiled again."""
+        if self._period_fn is None:
+            raise RuntimeError("period_op_scopes() reads the fused period "
+                               "program; this driver steps per epoch")
+        if self._period_inputs is None:
+            raise RuntimeError("period_op_scopes() needs the period program "
+                               "traced: run a segment first")
+        state = (self.store, self.directory, self.load_reg, self.sketch,
+                 self.repl, self.ovl, self.coord, self.metrics)
+        fn = self._period_fn
+        if self.backend == "dist":
+            fn, state = self._dist_period, self._dist_commit(*state)
+        hlo = fn.lower(*state, *self._period_inputs).compile().as_text()
+        return TEL.hlo_op_scopes(hlo, PERIOD_SCOPES, APPLY_SCOPES)
 
     def _note_keys(self, keys) -> None:
         """Fold one epoch's keys into the distinct-key window (sorted-unique
@@ -1527,7 +1610,7 @@ class EpochDriver:
                 jax.block_until_ready(out)
         (self.store, self.directory, self.load_reg, self.sketch, self.repl,
          self.ovl, self.coord, self.metrics, plan, node_ops, retries,
-         bounced, ostats, cstats, spans) = out
+         bounced, ostats, cstats, spans, digest, counts) = out
 
         self.host_syncs += 1   # the DES engine pulls the plan to the host
         issue = hops = None
@@ -1572,7 +1655,9 @@ class EpochDriver:
         # drops = pure store-capacity overflow delta; the overload plane's
         # shed/requeued/lost travel separately (the satellite fix for the
         # old conflation of capacity events with shed traffic)
-        overflow_now = int(self._sync(self.store.overflow).sum())
+        ovf_h, digest_h = self._pull_apply(self.store.overflow, digest,
+                                           counts)
+        overflow_now = int(ovf_h.sum())
         drops = overflow_now - self._last_overflow
         self._last_overflow = overflow_now
         if self.ovl is not None:
@@ -1628,6 +1713,7 @@ class EpochDriver:
             mis_served=int(cst[3]),
             stale_switches=int(cst[4]),
             coordination=self._coord_label(),
+            get_digest=int(digest_h),
         )
         if self.telemetry is not None:
             si, sf, cnt = spans
@@ -1790,12 +1876,13 @@ class EpochDriver:
                 jax.block_until_ready(out)
         (self.store, self.directory, self.load_reg, self.sketch, self.repl,
          self.ovl, self.coord, self.metrics, plan, node_ops, retries, ovf,
-         bounced, ostats, cstats, spans) = out
+         bounced, ostats, cstats, spans, digest, counts) = out
         return (jax.tree.map(lambda x: x[:L], plan),
                 node_ops[:L], retries[:L], ovf[:L], bounced[:L], ostats[:L],
                 cstats[:L],
                 None if spans is None
                 else jax.tree.map(lambda x: x[:L], spans),
+                digest[:L], jax.tree.map(lambda x: x[:L], counts),
                 opcodes_h)
 
     def _step_segment(self, e0: int, L: int):
@@ -1804,9 +1891,8 @@ class EpochDriver:
         the period boundary — plans/metrics stay on device until then.
         The fused dist driver runs the same period through
         :meth:`_scan_segment` instead (scan inside the shard_map)."""
-        plans, nops_l, rtr_l, ovf_l, bnc_l, ost_l, cst_l, spn_l, op_l = (
-            [], [], [], [], [], [], [], [], []
-        )
+        (plans, nops_l, rtr_l, ovf_l, bnc_l, ost_l, cst_l, spn_l, dig_l,
+         cnt_l, op_l) = ([], [], [], [], [], [], [], [], [], [], [])
         with self._timers.stage("route_apply"):
             for i in range(L):
                 opcodes, keys, end_keys, values = self.scenario.epoch(e0 + i)
@@ -1819,8 +1905,8 @@ class EpochDriver:
                 rng = jax.random.fold_in(self.key, e0 + i)
                 (self.store, self.directory, self.load_reg, self.sketch,
                  self.repl, self.ovl, self.coord, self.metrics, plan,
-                 node_ops, retries, bounced, ostats, cstats,
-                 spans) = self._step(
+                 node_ops, retries, bounced, ostats, cstats, spans, digest,
+                 counts) = self._step(
                     self.store, self.directory, self.load_reg, self.sketch,
                     self.repl, self.ovl, self.coord, self.metrics, q, rng,
                     jnp.int32(e0 + i)
@@ -1833,22 +1919,22 @@ class EpochDriver:
                 ost_l.append(ostats)
                 cst_l.append(cstats)
                 spn_l.append(spans)
-        plan = jax.tree.map(lambda *xs: jnp.stack(xs), *plans)
-        spans = (None if spn_l[0] is None
-                 else jax.tree.map(lambda *xs: jnp.stack(xs), *spn_l))
-        return (plan, jnp.stack(nops_l), jnp.stack(rtr_l), jnp.stack(ovf_l),
-                jnp.stack(bnc_l), jnp.stack(ost_l), jnp.stack(cst_l), spans,
+                dig_l.append(digest)
+                cnt_l.append(counts)
+        stack = lambda xs: jax.tree.map(lambda *x: jnp.stack(x), *xs)
+        spans = None if spn_l[0] is None else stack(spn_l)
+        return (stack(plans), jnp.stack(nops_l), jnp.stack(rtr_l),
+                jnp.stack(ovf_l), jnp.stack(bnc_l), jnp.stack(ost_l),
+                jnp.stack(cst_l), spans, jnp.stack(dig_l), stack(cnt_l),
                 np.stack(op_l))
 
     def _run_segment(self, e0: int, n: int) -> list[EpochMetrics]:
         ev0, en0, by0 = self._handle_events(e0)
         L = self._segment_len(e0, n)
-        if self._period_fn is not None:
-            (plan, node_ops, retries, ovf, bounced, ostats, cstats, spans,
-             opcodes_h) = self._scan_segment(e0, L)
-        else:
-            (plan, node_ops, retries, ovf, bounced, ostats, cstats, spans,
-             opcodes_h) = self._step_segment(e0, L)
+        segment = (self._scan_segment if self._period_fn is not None
+                   else self._step_segment)
+        (plan, node_ops, retries, ovf, bounced, ostats, cstats, spans,
+         digest, counts, opcodes_h) = segment(e0, L)
 
         cfg = self.cfg
         scfg = self.scenario.cfg
@@ -1879,7 +1965,7 @@ class EpochDriver:
             mks = np.asarray(makespan)
             node_ops_h = self._sync(node_ops)
             retries_h = self._sync(retries)
-            ovf_h = self._sync(ovf).astype(np.int64)
+            ovf_h, digest_h = self._pull_apply(ovf, digest, counts)
 
         p50s, p99s = latency_percentiles_batch(lat)
         p999s = p999_batch(lat)
@@ -1965,6 +2051,7 @@ class EpochDriver:
                 mis_served=int(cst_h[i, 3]),
                 stale_switches=int(cst_h[i, 4]),
                 coordination=self._coord_label(),
+                get_digest=int(digest_h[i]),
             ))
         if self.telemetry is not None:
             with self._timers.stage("telemetry"):

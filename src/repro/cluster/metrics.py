@@ -58,6 +58,10 @@ class EpochMetrics:
     mis_served: int = 0       # stale wrong-owner serves NOT redirected
     stale_switches: int = 0   # switch copies divergent at epoch end
     coordination: str = "none"
+    # ---- served replies ----
+    # the period program's digest of this epoch's GET replies
+    # (repro.core.store.get_digest: sum mod 2**32 of a per-GET mix)
+    get_digest: int = 0
 
     def to_row(self) -> dict:
         row = dataclasses.asdict(self)

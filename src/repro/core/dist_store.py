@@ -59,7 +59,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core import keys as K
 from repro.core import routing as R
 from repro.core.directory import Directory
-from repro.core.store import StoreState, Responses, shard_apply
+from repro.core import store as S
+from repro.core.store import ApplyCounts, StoreState, Responses, shard_apply
 
 DROP = -1  # bucket slot for dead/overflowed queries
 
@@ -160,8 +161,10 @@ def _make_bucket_plane(cfg: DistConfig, n_shards: int):
 
     Returns ``plane(store, directory, q_local, load_reg, rng, dirty,
     queue_pen) -> (store', resp, directory', load_reg', decision, picked,
-    bounced, bucket_overflow)``; ``load_reg``/``rng``/``dirty``/
-    ``queue_pen`` ride through untouched on the paths that ignore them.
+    bounced, bucket_overflow, counts)``, ``counts`` this device's
+    :class:`~repro.core.store.ApplyCounts` over its read and write
+    rounds; ``load_reg``/``rng``/``dirty``/``queue_pen`` ride through
+    untouched on the paths that ignore them.
     """
     axis = cfg.axis
     spread = cfg.read_spread
@@ -172,48 +175,62 @@ def _make_bucket_plane(cfg: DistConfig, n_shards: int):
         me = jax.lax.axis_index(axis)
         slab_keys, slab_vals = _local_slab(store)
         picked = bounced = None
-        base_dir = directory
-        if craq:
-            base_load = load_reg
-            decision, directory, load_reg, picked, bounced = (
-                R.route_load_aware_dirty(
-                    directory, q, load_reg, dirty, jax.random.fold_in(rng, me),
+        with jax.named_scope(S.ROUTE):
+            base_dir = directory
+            if craq:
+                base_load = load_reg
+                decision, directory, load_reg, picked, bounced = (
+                    R.route_load_aware_dirty(
+                        directory, q, load_reg, dirty, jax.random.fold_in(rng, me),
+                        queue_pen=queue_pen,
+                    )
+                )
+                load_reg = base_load + jax.lax.psum(load_reg - base_load, axis)
+            elif spread:
+                base_load = load_reg
+                # distinct draws per device (each routes its own batch slice)
+                decision, directory, load_reg = R.route_load_aware(
+                    directory, q, load_reg, jax.random.fold_in(rng, me),
                     queue_pen=queue_pen,
                 )
+                load_reg = base_load + jax.lax.psum(load_reg - base_load, axis)
+            else:
+                decision, directory = R.route(directory, q)
+            # counters were bumped from the *local* slice only; make the
+            # statistics registers globally consistent (replicated out_spec)
+            directory = dataclasses.replace(
+                directory,
+                read_count=base_dir.read_count
+                + jax.lax.psum(directory.read_count - base_dir.read_count, axis),
+                write_count=base_dir.write_count
+                + jax.lax.psum(directory.write_count - base_dir.write_count, axis),
             )
-            load_reg = base_load + jax.lax.psum(load_reg - base_load, axis)
-        elif spread:
-            base_load = load_reg
-            # distinct draws per device (each routes its own batch slice)
-            decision, directory, load_reg = R.route_load_aware(
-                directory, q, load_reg, jax.random.fold_in(rng, me),
-                queue_pen=queue_pen,
-            )
-            load_reg = base_load + jax.lax.psum(load_reg - base_load, axis)
-        else:
-            decision, directory = R.route(directory, q)
-        # counters were bumped from the *local* slice only; make the
-        # statistics registers globally consistent (replicated out_spec)
-        directory = dataclasses.replace(
-            directory,
-            read_count=base_dir.read_count
-            + jax.lax.psum(directory.read_count - base_dir.read_count, axis),
-            write_count=base_dir.write_count
-            + jax.lax.psum(directory.write_count - base_dir.write_count, axis),
-        )
+        with jax.named_scope(S.APPLY):
+            new_store, resp, bucket_ovf, counts = _apply(
+                store, q, decision, slab_keys, slab_vals)
+        return (new_store, resp, directory, load_reg, decision, picked,
+                bounced, bucket_ovf, counts)
+
+    def _apply(store, q, decision, slab_keys, slab_vals):
+        """The read round, the ``r_max`` write rounds and the local slab
+        mutations: ``(store', resp, bucket_overflow, counts)``."""
         is_write = (q.opcode == K.OP_PUT) | (q.opcode == K.OP_DEL)
         cap = cfg.bucket_cap
         n_slots = n_shards * cap
+        exchange = partial(jax.named_scope, S.EXCHANGE)
 
         # --- reads: one a2a round to the tail, replies via inverse a2a ---
-        read_target = jnp.where(
-            ~is_write & (q.key != K.EMPTY_KEY), decision.target, DROP
-        )
-        slot, ovf_r = bucketize(read_target, n_shards, cap)
-        bkeys = scatter_to_buckets(slot, q.key, n_slots, K.EMPTY_KEY)
-        bop = scatter_to_buckets(slot, q.opcode, n_slots, jnp.int32(K.OP_GET))
-        bend = scatter_to_buckets(slot, q.end_key, n_slots, jnp.uint32(0))
-        bkeys, bop, bend = (_a2a(x, axis, n_shards) for x in (bkeys, bop, bend))
+        with exchange():
+            read_target = jnp.where(
+                ~is_write & (q.key != K.EMPTY_KEY), decision.target, DROP
+            )
+            slot, ovf_r = bucketize(read_target, n_shards, cap)
+            bkeys = scatter_to_buckets(slot, q.key, n_slots, K.EMPTY_KEY)
+            bop = scatter_to_buckets(slot, q.opcode, n_slots,
+                                     jnp.int32(K.OP_GET))
+            bend = scatter_to_buckets(slot, q.end_key, n_slots, jnp.uint32(0))
+            bkeys, bop, bend = (_a2a(x, axis, n_shards)
+                                for x in (bkeys, bop, bend))
 
         inbound = R.QueryBatch(
             opcode=bop, key=bkeys, end_key=bend,
@@ -221,51 +238,60 @@ def _make_bucket_plane(cfg: DistConfig, n_shards: int):
         )
         read_mine = (inbound.opcode == K.OP_GET) | (inbound.opcode == K.OP_SCAN)
         read_mine &= inbound.key != K.EMPTY_KEY
-        slab_keys, slab_vals, _, resp_in = shard_apply(
+        slab_keys, slab_vals, _, resp_in, counts = shard_apply(
             slab_keys, slab_vals, inbound, read_mine,
             jnp.zeros_like(read_mine),  # no writes in the read round
             max_scan_results=cfg.max_scan_results,
         )
         # replies travel back through the inverse all_to_all
-        back = jax.tree.map(lambda x: _a2a(x, axis, n_shards), resp_in)
-        resp = Responses(
-            value=gather_from_buckets(slot, back.value, 0.0),
-            found=gather_from_buckets(slot, back.found, False),
-            scan_values=gather_from_buckets(slot, back.scan_values, 0.0),
-            scan_keys=gather_from_buckets(slot, back.scan_keys, K.EMPTY_KEY),
-            scan_count=gather_from_buckets(slot, back.scan_count, jnp.int32(0)),
-        )
+        with exchange():
+            back = jax.tree.map(lambda x: _a2a(x, axis, n_shards), resp_in)
+            resp = Responses(
+                value=gather_from_buckets(slot, back.value, 0.0),
+                found=gather_from_buckets(slot, back.found, False),
+                scan_values=gather_from_buckets(slot, back.scan_values, 0.0),
+                scan_keys=gather_from_buckets(slot, back.scan_keys,
+                                              K.EMPTY_KEY),
+                scan_count=gather_from_buckets(slot, back.scan_count,
+                                               jnp.int32(0)),
+            )
 
         # --- writes: r sequential a2a rounds along the chain (Fig 9a) ---
         ovf_w = jnp.zeros((), ovf_r.dtype)
         r_max = decision.chain.shape[1]
         for pos in range(r_max):
-            live = is_write & (pos < decision.chain_len) & (q.key != K.EMPTY_KEY)
-            wt = jnp.where(live, decision.chain[:, pos], DROP)
-            wslot, ovf = bucketize(wt, n_shards, cap)
-            ovf_w += ovf
-            wkeys = scatter_to_buckets(wslot, q.key, n_slots, K.EMPTY_KEY)
-            wop = scatter_to_buckets(wslot, q.opcode, n_slots, jnp.int32(K.OP_GET))
-            wval = scatter_to_buckets(wslot, q.value, n_slots, 0.0)
-            wkeys, wop, wval = (_a2a(x, axis, n_shards) for x in (wkeys, wop, wval))
+            with exchange():
+                live = (is_write & (pos < decision.chain_len)
+                        & (q.key != K.EMPTY_KEY))
+                wt = jnp.where(live, decision.chain[:, pos], DROP)
+                wslot, ovf = bucketize(wt, n_shards, cap)
+                ovf_w += ovf
+                wkeys = scatter_to_buckets(wslot, q.key, n_slots, K.EMPTY_KEY)
+                wop = scatter_to_buckets(wslot, q.opcode, n_slots,
+                                         jnp.int32(K.OP_GET))
+                wval = scatter_to_buckets(wslot, q.value, n_slots, 0.0)
+                wkeys, wop, wval = (_a2a(x, axis, n_shards)
+                                    for x in (wkeys, wop, wval))
             wq = R.QueryBatch(
                 opcode=wop, key=wkeys, end_key=jnp.zeros_like(wkeys), value=wval
             )
             write_mine = ((wq.opcode == K.OP_PUT) | (wq.opcode == K.OP_DEL)) & (
                 wq.key != K.EMPTY_KEY
             )
-            slab_keys, slab_vals, dropped, wresp = shard_apply(
+            slab_keys, slab_vals, dropped, wresp, wcounts = shard_apply(
                 slab_keys, slab_vals, wq, jnp.zeros_like(write_mine), write_mine,
                 max_scan_results=1,
             )
+            counts = jax.tree.map(jnp.add, counts, wcounts)
             if pos == 0:
                 put_dropped = dropped
             else:
                 put_dropped = put_dropped + dropped
             # tail replies: DEL found flag returns from the last chain pos
-            wback = _a2a(wresp.found, axis, n_shards)
-            at_tail = is_write & (pos == decision.chain_len - 1)
-            got = gather_from_buckets(wslot, wback, False)
+            with exchange():
+                wback = _a2a(wresp.found, axis, n_shards)
+                at_tail = is_write & (pos == decision.chain_len - 1)
+                got = gather_from_buckets(wslot, wback, False)
             resp = dataclasses.replace(
                 resp, found=jnp.where(at_tail, got, resp.found)
             )
@@ -274,8 +300,7 @@ def _make_bucket_plane(cfg: DistConfig, n_shards: int):
             keys=slab_keys[None], values=slab_vals[None],
             overflow=store.overflow + put_dropped,
         )
-        return (new_store, resp, directory, load_reg, decision, picked,
-                bounced, (ovf_r + ovf_w).astype(jnp.int32))
+        return (new_store, resp, (ovf_r + ovf_w).astype(jnp.int32), counts)
 
     return plane
 
@@ -299,7 +324,9 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
     same psum-delta trick used for the statistics counters keeps it
     globally consistent.  ``cfg.return_decision`` adds the sharded routing
     decision (target/chain/chain_len) to ``metrics`` so the caller can
-    build DES hop plans without routing a second time.
+    build DES hop plans without routing a second time.  ``metrics`` also
+    holds the batch's :class:`~repro.core.store.ApplyCounts` fields,
+    summed over the devices.
     """
     n_shards = mesh.shape[cfg.axis]
     axis = cfg.axis
@@ -334,7 +361,7 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
                 )
             else:
                 decision, directory = R.route(directory, gq)
-            new_keys, new_vals, dropped, resp = _apply_full(
+            new_keys, new_vals, dropped, resp, counts = _apply_full(
                 slab_keys, slab_vals, gq, decision, me, cfg.max_scan_results
             )
             # each read answered by exactly one shard -> psum combines
@@ -358,6 +385,7 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
             metrics = {
                 "bucket_overflow": overflow,
                 "a2a_rounds": jnp.zeros((), jnp.int32),
+                **_global_counts(counts),
             }
             if cfg.return_decision:
                 metrics.update(_slice_decision(decision, me, q.opcode.shape[0]))
@@ -375,12 +403,13 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
 
         # ---- bucket_a2a (the shared per-device data plane) ----
         (new_store, resp, directory, load_reg, decision, picked, bounced,
-         bucket_ovf) = bucket_plane(
+         bucket_ovf, counts) = bucket_plane(
             store, directory, q, load_reg, rng, dirty, queue_pen
         )
         metrics = {
             "bucket_overflow": bucket_ovf,
             "a2a_rounds": jnp.int32(1 + decision.chain.shape[1]),
+            **_global_counts(counts),
         }
         if cfg.return_decision:
             metrics.update({
@@ -408,6 +437,9 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
     def _ag(x, ax):
         return jax.lax.all_gather(x, ax, axis=0, tiled=True)
 
+    def _global_counts(counts):
+        return jax.lax.psum(counts, axis)._asdict()
+
     def _mask_resp(x):
         if x.dtype == jnp.uint32:  # scan_keys sentinel: use min so EMPTY loses
             return x
@@ -419,7 +451,7 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
         member_live = jnp.arange(r_max)[None, :] < decision.chain_len[:, None]
         read_mine = (decision.target == me) & ~is_write
         write_mine = is_write & jnp.any((decision.chain == me) & member_live, axis=1)
-        new_keys, new_vals, dropped, resp = shard_apply(
+        new_keys, new_vals, dropped, resp, counts = shard_apply(
             slab_keys, slab_vals, gq, read_mine, write_mine, max_scan_results=max_scan
         )
         # zero out non-owned replies so psum combines cleanly; keys use min
@@ -431,7 +463,7 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
             scan_keys=jnp.where(owner[:, None], resp.scan_keys, 0).astype(jnp.uint32),
             scan_count=jnp.where(owner, resp.scan_count, 0),
         )
-        return new_keys, new_vals, dropped, resp
+        return new_keys, new_vals, dropped, resp, counts
 
     store_spec = StoreState(keys=P(axis), values=P(axis), overflow=P(axis))
     dir_spec = jax.tree.map(lambda _: P(), directory_template)
@@ -440,7 +472,8 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
         value=P(axis), found=P(axis), scan_values=P(axis),
         scan_keys=P(axis), scan_count=P(axis),
     )
-    metric_spec = {"bucket_overflow": P(), "a2a_rounds": P()}
+    metric_spec = {"bucket_overflow": P(), "a2a_rounds": P(),
+                   **{f: P() for f in ApplyCounts._fields}}
     if cfg.return_decision:
         metric_spec.update({"ridx": P(axis), "target": P(axis),
                             "chain": P(axis), "chain_len": P(axis)})
@@ -485,7 +518,7 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
 
 
 def make_dist_period(mesh, directory_template: Directory, cfg: DistConfig,
-                     *, pre, observe, fold_ovl: bool):
+                     *, pre, observe, fold_ovl: bool, on_trace=None):
     """Build the whole-period dist program: ONE shard_map whose per-device
     body runs a ``lax.scan`` over the period's epochs, each scan step
     executing the bounded-bucket a2a data plane on the local batch slice
@@ -510,6 +543,8 @@ def make_dist_period(mesh, directory_template: Directory, cfg: DistConfig,
     ring — each an empty pytree / None when its plane is off).
     ``fold_ovl`` mirrors the driver's overload-rng fold (a fold_in, not
     a wider split, so the disabled path's rng streams are untouched).
+    ``on_trace(qs, rngs, live, eids)``, if given, is called once per
+    trace with the staged inputs (their shapes; it runs at trace time).
 
     Signature of the returned jitted fn (donated like the oracle period
     scan — store slabs, load/sketch/repl/overload registers, the
@@ -520,9 +555,11 @@ def make_dist_period(mesh, directory_template: Directory, cfg: DistConfig,
        qs, rngs, live, eids)
         -> (store, directory, load_reg, sketch, repl, ovl, coord, metrics,
             plans, node_ops, bucket_overflow, overflow_totals, bounced,
-            ostats, cstats, spans)
+            ostats, cstats, spans, get_digests, counts)
 
-    with ``qs`` the period's (P, B, ...) query pytree REPLICATED (each
+    with ``get_digests`` the (P,) :func:`~repro.core.store.get_digest` of
+    each epoch's GET replies and ``counts`` its
+    :class:`~repro.core.store.ApplyCounts`, both over all devices, ``qs`` the period's (P, B, ...) query pytree REPLICATED (each
     device slices its share for the data plane and keeps the whole batch
     for observe), ``live`` the (P,) real-epoch mask (dead padding epochs
     compute but do not commit), ``eids`` the (P,) absolute epoch ids.
@@ -540,6 +577,8 @@ def make_dist_period(mesh, directory_template: Directory, cfg: DistConfig,
 
     def period_device(store, directory, load_reg, sketch, repl, ovl, coord,
                       metrics, qs, rngs, live, eids):
+        if on_trace is not None:
+            on_trace(qs, rngs, live, eids)
         me = jax.lax.axis_index(axis)
 
         def scan_body(carry, xs):
@@ -551,26 +590,34 @@ def make_dist_period(mesh, directory_template: Directory, cfg: DistConfig,
             # the same rng discipline as the per-epoch driver step
             r_ovl = jax.random.fold_in(rng, 0x0F10AD) if fold_ovl else rng
             r_route, r_plan = jax.random.split(rng)
-            dirty, queue_pen = pre(repl, ovl)
+            with jax.named_scope(S.ROUTE):
+                dirty, queue_pen = pre(repl, ovl)
             q_local = jax.tree.map(
                 lambda x: jax.lax.dynamic_slice_in_dim(x, me * Bl, Bl, 0), q
             )
-            (store2, _resp, directory2, load_reg2, decision, picked,
-             bounced, bucket_ovf) = plane(
+            (store2, resp, directory2, load_reg2, decision, picked,
+             bounced, bucket_ovf, counts) = plane(
                 store, directory, q_local, load_reg, r_route, dirty,
                 queue_pen,
             )
+            with jax.named_scope(S.APPLY), jax.named_scope(S.GET):
+                # the slices' digests add up to the whole batch's
+                digest = jax.lax.psum(S.get_digest(
+                    q_local.opcode, q_local.key, resp.value, resp.found,
+                    base=me * Bl), axis)
+            counts = jax.lax.psum(counts, axis)
             # reconstruct the global decision for the replicated observe
-            ag = lambda x: jax.lax.all_gather(x, axis, axis=0, tiled=True)
-            ridx, target = ag(decision.ridx), ag(decision.target)
-            chain, clen = ag(decision.chain), ag(decision.chain_len)
-            if craq:
-                picked_g, bounced_g = ag(picked), ag(bounced)
-            else:
-                # placeholders keep observe's signature mode-independent
-                # (exactly the per-epoch step's substitution)
-                picked_g = target
-                bounced_g = jnp.zeros((B,), jnp.bool_)
+            with jax.named_scope(S.OBSERVE):
+                ag = lambda x: jax.lax.all_gather(x, axis, axis=0, tiled=True)
+                ridx, target = ag(decision.ridx), ag(decision.target)
+                chain, clen = ag(decision.chain), ag(decision.chain_len)
+                if craq:
+                    picked_g, bounced_g = ag(picked), ag(bounced)
+                else:
+                    # placeholders keep observe's signature mode-independent
+                    # (exactly the per-epoch step's substitution)
+                    picked_g = target
+                    bounced_g = jnp.zeros((B,), jnp.bool_)
             (sketch2, plan, node_ops, repl2, ovl2, coord2, metrics2,
              ostats, cstats, spans) = observe(
                 q, ridx, target, chain, clen, sketch, r_plan, repl,
@@ -578,20 +625,23 @@ def make_dist_period(mesh, directory_template: Directory, cfg: DistConfig,
             )
             if not spread:
                 # tail-read path: registers tracked for parity (same units)
-                load_reg2 = load_reg2 + node_ops.astype(jnp.uint32)
-            keep = lambda new, old: jnp.where(lv, new, old)
-            store2 = jax.tree.map(keep, store2, store)
-            carry2 = (store2, jax.tree.map(keep, directory2, directory),
-                      keep(load_reg2, load_reg), keep(sketch2, sketch),
-                      jax.tree.map(keep, repl2, repl),
-                      jax.tree.map(keep, ovl2, ovl),
-                      jax.tree.map(keep, coord2, coord),
-                      jax.tree.map(keep, metrics2, metrics))
-            # global overflow total (the store is sharded, one node per
-            # device — psum of the local sum is jnp.sum(store.overflow))
-            ovf = jax.lax.psum(jnp.sum(store2.overflow), axis)
+                with jax.named_scope(S.APPLY):
+                    load_reg2 = load_reg2 + node_ops.astype(jnp.uint32)
+            with jax.named_scope(S.COMMIT):
+                keep = lambda new, old: jnp.where(lv, new, old)
+                store2 = jax.tree.map(keep, store2, store)
+                carry2 = (store2, jax.tree.map(keep, directory2, directory),
+                          keep(load_reg2, load_reg), keep(sketch2, sketch),
+                          jax.tree.map(keep, repl2, repl),
+                          jax.tree.map(keep, ovl2, ovl),
+                          jax.tree.map(keep, coord2, coord),
+                          jax.tree.map(keep, metrics2, metrics))
+                # global overflow total (the store is sharded, one node
+                # per device — psum of the local sum is
+                # jnp.sum(store.overflow))
+                ovf = jax.lax.psum(jnp.sum(store2.overflow), axis)
             return carry2, (plan, node_ops, bucket_ovf, ovf, bounced_g,
-                            ostats, cstats, spans)
+                            ostats, cstats, spans, digest, counts)
 
         carry, outs = jax.lax.scan(
             scan_body,
@@ -609,7 +659,7 @@ def make_dist_period(mesh, directory_template: Directory, cfg: DistConfig,
     in_specs = (store_spec, P(), P(), P(), P(), P(), P(), P(), P(), P(),
                 P(), P())
     out_specs = (store_spec, P(), P(), P(), P(), P(), P(), P(),
-                 P(), P(), P(), P(), P(), P(), P(), P())
+                 P(), P(), P(), P(), P(), P(), P(), P(), P(), P())
     fn = _shard_map(period_device, mesh, in_specs, out_specs)
     return jax.jit(fn, donate_argnums=(0, 2, 3, 4, 5, 6, 7))
 

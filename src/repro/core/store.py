@@ -29,20 +29,41 @@ Capacity overflow (more live keys than ``capacity`` after a PUT batch) drops
 the largest keys of the slab and reports a per-shard ``overflow`` count —
 the controller reacts by splitting the hot sub-range and migrating half of
 it (paper §4.1.1 "divided into two smaller sub-ranges").
+
+What a batch cost and served is visible from outside: ``shard_apply``
+counts the slab rows its delete and put branches rewrite and the PUT rows
+it applies (:class:`ApplyCounts`), :func:`get_digest` folds a batch's GET
+replies into one uint32, and the stages run under the named scopes of
+:data:`PERIOD_SCOPES` / :data:`APPLY_SCOPES`, so a profile's device ops
+map to them.
 """
 
 from __future__ import annotations
 
 import dataclasses
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import keys as K
 from repro.core.routing import QueryBatch, RoutingDecision
 
 EMPTY = K.EMPTY_KEY
+
+# Named scopes (``jax.named_scope``) of the period program, so that a
+# profile's device ops map to the stage that issued them.  The top-level
+# stages of one epoch, and the second-level scopes inside ``apply``:
+# GET lookups and their reply pick, range scans, deletes, the PUT batch's
+# last-write dedupe, the slab merge, and (on a mesh) the all-to-all
+# rounds.  Readers import these tuples; the strings live only here.
+ROUTE, APPLY, OBSERVE, COMMIT = "route", "apply", "observe", "commit"
+PERIOD_SCOPES = (ROUTE, APPLY, OBSERVE, COMMIT)
+GET, SCAN, DELETE, DEDUPE, MERGE, EXCHANGE = (
+    "get", "scan", "delete", "dedupe", "merge", "exchange")
+APPLY_SCOPES = (GET, SCAN, DELETE, DEDUPE, MERGE, EXCHANGE)
 
 
 @partial(
@@ -97,6 +118,16 @@ class Responses:
     scan_values: jnp.ndarray
     scan_keys: jnp.ndarray
     scan_count: jnp.ndarray
+
+
+class ApplyCounts(NamedTuple):
+    """What applying one batch cost the slabs, summed over the shards:
+    ``slab_rows_rewritten`` counts the slab rows a delete or put branch
+    rewrote (the whole capacity of each shard whose branch ran), and
+    ``put_rows`` the PUT rows applied at chain members.  Both () int32."""
+
+    slab_rows_rewritten: jnp.ndarray
+    put_rows: jnp.ndarray
 
 
 def make_store(num_shards: int, capacity: int, value_dim: int) -> StoreState:
@@ -319,6 +350,60 @@ def put_sorted(slab_keys: jnp.ndarray, slab_vals: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
+# the digest of a batch's GET replies
+# ---------------------------------------------------------------------------
+
+_MIX_INDEX = 0x9E3779B1
+_MIX_FOUND = 0x85EBCA77
+_MIX_WORD = 0xC2B2AE3D
+
+
+def _fmix32(h, xp):
+    """MurmurHash3's 32-bit finalizer on uint32 values."""
+    h = h ^ (h >> 16)
+    h = h * xp.uint32(0x85EBCA6B)
+    h = h ^ (h >> 13)
+    h = h * xp.uint32(0xC2B2AE35)
+    return h ^ (h >> 16)
+
+
+def _digest(xp, opcode, keys, words, found, index):
+    """Sum mod 2**32, over the GET rows, of a mix of (batch index, key,
+    found, value words); every operand uint32 but ``opcode``/``found``."""
+    col = np.arange(1, 2 * words.shape[1], 2, dtype=np.uint32) * np.uint32(
+        _MIX_WORD)
+    vsum = xp.sum(words * col, axis=1, dtype=xp.uint32)
+    h = _fmix32(index * xp.uint32(_MIX_INDEX) + keys, xp)
+    h = _fmix32((h ^ found.astype(xp.uint32) * xp.uint32(_MIX_FOUND)) + vsum,
+                xp)
+    return xp.sum(xp.where(opcode == K.OP_GET, h, xp.uint32(0)),
+                  dtype=xp.uint32)
+
+
+def get_digest(opcode: jnp.ndarray, keys: jnp.ndarray, value: jnp.ndarray,
+               found: jnp.ndarray, base=0) -> jnp.ndarray:
+    """() uint32 digest of a batch's GET replies: the sum mod 2**32, over
+    the GETs, of a fixed mix of (batch index, key, found, the value's
+    float32 bits).  ``base`` is the batch index of row 0, so the digests
+    of a batch's slices add up to the whole batch's.  Twin:
+    :func:`get_digest_np`."""
+    index = (jnp.arange(keys.shape[0], dtype=jnp.uint32)
+             + jnp.asarray(base).astype(jnp.uint32))
+    words = jax.lax.bitcast_convert_type(value.astype(jnp.float32),
+                                         jnp.uint32)
+    return _digest(jnp, opcode, keys.astype(jnp.uint32), words, found, index)
+
+
+def get_digest_np(opcode, keys, value, found) -> int:
+    """:func:`get_digest` of a whole batch, in numpy."""
+    keys = np.asarray(keys, np.uint32)
+    words = np.ascontiguousarray(value, np.float32).view(np.uint32)
+    index = np.arange(keys.shape[0], dtype=np.uint32)
+    return int(_digest(np, np.asarray(opcode), keys, words,
+                       np.asarray(found, bool), index))
+
+
+# ---------------------------------------------------------------------------
 # shard-level mixed-opcode batch application
 # ---------------------------------------------------------------------------
 
@@ -336,15 +421,23 @@ def shard_apply(
 
     read_mine:  (B,) this shard serves the GET/SCAN (it is the chain tail)
     write_mine: (B,) this shard applies the PUT/DEL (it is a chain member)
+
+    Returns ``(slab_keys, slab_vals, dropped, resp, counts)`` with
+    ``counts`` this shard's :class:`ApplyCounts`.
     """
     is_get = (q.opcode == K.OP_GET) & read_mine
     is_scan = (q.opcode == K.OP_SCAN) & read_mine
     is_del = (q.opcode == K.OP_DEL) & write_mine
     is_put = (q.opcode == K.OP_PUT) & write_mine
     B, V = q.value.shape
+    C = slab_keys.shape[0]
+    rewrote = jnp.int32(C)
+    untouched = jnp.zeros((), jnp.int32)
 
     # --- reads against pre-batch state ---
-    get_vals, get_found = slab_get(slab_keys, slab_vals, jnp.where(is_get, q.key, EMPTY))
+    with jax.named_scope(GET):
+        get_vals, get_found = slab_get(slab_keys, slab_vals,
+                                       jnp.where(is_get, q.key, EMPTY))
 
     # scans, deletes and puts cost O(C) per shard (slab pad copy,
     # compaction, merge): each is skipped when the batch holds none.  The
@@ -365,27 +458,41 @@ def shard_apply(
                 jnp.zeros((B, max_scan_results, V), slab_vals.dtype),
                 jnp.zeros((B,), jnp.int32))
 
-    sk, sv, scount = jax.lax.cond(jnp.any(q.opcode == K.OP_SCAN), scan,
-                                  no_scan, None)
-    scount = jnp.where(is_scan, scount, 0)
-    sk = jnp.where(is_scan[:, None], sk, EMPTY)
-    sv = jnp.where(is_scan[:, None, None], sv, 0.0)
+    with jax.named_scope(SCAN):
+        sk, sv, scount = jax.lax.cond(jnp.any(q.opcode == K.OP_SCAN), scan,
+                                      no_scan, None)
+        scount = jnp.where(is_scan, scount, 0)
+        sk = jnp.where(is_scan[:, None], sk, EMPTY)
+        sv = jnp.where(is_scan[:, None, None], sv, 0.0)
 
     # --- deletes ---
-    del_keys = jnp.where(is_del, q.key, EMPTY)
-    del_found = _member_sorted(slab_keys, del_keys)
-    slab_keys, slab_vals = jax.lax.cond(
-        jnp.any(q.opcode == K.OP_DEL),
-        lambda kv: slab_delete(*kv, del_keys), lambda kv: kv,
-        (slab_keys, slab_vals))
+    with jax.named_scope(DELETE):
+        del_keys = jnp.where(is_del, q.key, EMPTY)
+
+        def delete(kv):
+            # the hits are looked up in the branch: a batch with no DEL
+            # pays no search of the slab for them
+            return (*slab_delete(*kv, del_keys), rewrote,
+                    _member_sorted(kv[0], del_keys))
+
+        slab_keys, slab_vals, del_rows, del_found = jax.lax.cond(
+            jnp.any(q.opcode == K.OP_DEL), delete,
+            lambda kv: (*kv, untouched, jnp.zeros((B,), bool)),
+            (slab_keys, slab_vals))
 
     # --- puts ---
     put_keys = jnp.where(is_put, q.key, EMPTY)
     put_vals = jnp.where(is_put[:, None], q.value, 0.0)
-    slab_keys, slab_vals, dropped = jax.lax.cond(
-        jnp.any(q.opcode == K.OP_PUT),
-        lambda kv: slab_put(*kv, put_keys, put_vals),
-        lambda kv: (*kv, jnp.zeros((), jnp.int32)),
+
+    def put(kv):
+        with jax.named_scope(DEDUPE):
+            pk, pv = _dedupe_last_write(put_keys, put_vals)
+        with jax.named_scope(MERGE):
+            return (*put_sorted(*kv, pk, pv), rewrote)
+
+    slab_keys, slab_vals, dropped, put_rewrote = jax.lax.cond(
+        jnp.any(q.opcode == K.OP_PUT), put,
+        lambda kv: (*kv, jnp.zeros((), jnp.int32), untouched),
         (slab_keys, slab_vals))
 
     resp = Responses(
@@ -395,7 +502,9 @@ def shard_apply(
         scan_keys=sk,
         scan_count=scount,
     )
-    return slab_keys, slab_vals, dropped, resp
+    counts = ApplyCounts(slab_rows_rewritten=del_rows + put_rewrote,
+                         put_rows=jnp.sum(is_put.astype(jnp.int32)))
+    return slab_keys, slab_vals, dropped, resp, counts
 
 
 def apply_routed(
@@ -412,6 +521,20 @@ def apply_routed(
     by the routed target (the chain tail); writes are applied by every live
     chain member — the end state chain replication converges to (§4.1.2).
     """
+    store, resp, _ = apply_routed_counted(
+        store, q, decision, max_scan_results=max_scan_results)
+    return store, resp
+
+
+def apply_routed_counted(
+    store: StoreState,
+    q: QueryBatch,
+    decision: RoutingDecision,
+    *,
+    max_scan_results: int = 8,
+) -> tuple[StoreState, Responses, ApplyCounts]:
+    """:func:`apply_routed`, also returning its :class:`ApplyCounts`
+    summed over the shards."""
     N = store.num_shards
     is_write = (q.opcode == K.OP_PUT) | (q.opcode == K.OP_DEL)
     r_max = decision.chain.shape[1]
@@ -430,35 +553,37 @@ def apply_routed(
     # O(capacity).  At 8 x 1M slots of 128-byte values and a 4,096-op
     # batch, a v5e compile's memory_analysis gives 17.31 GiB for the
     # vmapped form (over a 16 GB chip) and 4.53 GiB for this one
-    new_keys, new_vals, dropped, resps = jax.lax.map(
+    new_keys, new_vals, dropped, resps, counts = jax.lax.map(
         lambda a: one_shard(*a), (store.keys, store.values, shard_ids))
 
     # combine per-shard responses: each read is answered by exactly one
     # shard, the routed target.  A gather, not a one-hot matmul: on a TPU
     # an f32 matmul at default precision rounds the values through bf16.
     # Unrouted queries (target NO_NODE) read as a miss, as before.
-    routed = (decision.target >= 0) & (decision.target < N)
-    owner = jnp.where(routed, decision.target, 0).astype(jnp.int32)
-    b = jnp.arange(owner.shape[0])
+    with jax.named_scope(GET):
+        routed = (decision.target >= 0) & (decision.target < N)
+        owner = jnp.where(routed, decision.target, 0).astype(jnp.int32)
+        b = jnp.arange(owner.shape[0])
 
-    def pick(x):
-        hit = routed.reshape(routed.shape + (1,) * (x.ndim - 2))
-        return jnp.where(hit, x[owner, b], jnp.zeros((), x.dtype))
+        def pick(x):
+            hit = routed.reshape(routed.shape + (1,) * (x.ndim - 2))
+            return jnp.where(hit, x[owner, b], jnp.zeros((), x.dtype))
 
-    value = pick(resps.value)
-    found = pick(resps.found)
-    scan_values = pick(resps.scan_values)
-    scan_count = pick(resps.scan_count)
-    scan_keys = jnp.take_along_axis(
-        resps.scan_keys, decision.target[None, :, None].astype(jnp.int32), axis=0
-    )[0]
+        value = pick(resps.value)
+        found = pick(resps.found)
+        scan_values = pick(resps.scan_values)
+        scan_count = pick(resps.scan_count)
+        scan_keys = jnp.take_along_axis(
+            resps.scan_keys, decision.target[None, :, None].astype(jnp.int32), axis=0
+        )[0]
 
     new_store = StoreState(
         keys=new_keys, values=new_vals, overflow=store.overflow + dropped
     )
-    return new_store, Responses(
+    resp = Responses(
         value=value, found=found, scan_values=scan_values, scan_keys=scan_keys, scan_count=scan_count
     )
+    return new_store, resp, jax.tree.map(jnp.sum, counts)
 
 
 def store_fill(store: StoreState) -> jnp.ndarray:

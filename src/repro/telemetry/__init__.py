@@ -11,7 +11,8 @@ p999* and *which pipeline stage burns the time*:
     attribution.py — exact latency decomposition into
                      {queue, inflation, bounce, retry_backoff, service}
     export.py      — Chrome-trace / JSONL span-tree exports
-    profiler.py    — pipeline stage timers + kernel roofline rows
+    profiler.py    — pipeline stage timers and counters, and the map
+                     from the period program's device ops to its stages
     flight.py      — ring-buffer flight recorder with postmortem dumps
     recorder.py    — the per-run host accumulator the driver feeds
     metrics.py     — the fleet metrics plane: a (window, n_series) ring
@@ -47,9 +48,10 @@ from repro.telemetry.metrics import (
 )
 from repro.telemetry.slo import SLO, AlertEngine
 from repro.telemetry.profiler import (
+    OpScopes,
     StageTimers,
-    fmt_roofline_md,
-    kernel_roofline_rows,
+    hlo_op_scopes,
+    scope_seconds,
 )
 from repro.telemetry.recorder import TelemetryRecorder
 from repro.telemetry.trace import (
@@ -69,7 +71,7 @@ __all__ = [
     "collect_spans", "sample_mask", "rate_threshold",
     "BUCKETS", "decompose", "reconstruct", "tail_attribution",
     "chrome_trace", "link_retries", "span_tree", "write_jsonl",
-    "StageTimers", "kernel_roofline_rows", "fmt_roofline_md",
+    "StageTimers", "OpScopes", "hlo_op_scopes", "scope_seconds",
     "FlightRecorder",
     "MetricsConfig", "MetricsState", "build_layout", "series_view",
     "to_openmetrics", "SLO", "AlertEngine", "incident",

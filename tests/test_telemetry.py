@@ -17,7 +17,7 @@ Pins the tentpole contracts:
   its per-row loop oracle, ``EpochMetrics`` row round-trip,
   ``summarize`` key order;
 * the export/profiler/flight-recorder halves: span trees, Chrome-trace
-  structure, stage timers, kernel roofline rows, postmortem dumps.
+  structure, stage timers and counters, postmortem dumps.
 """
 
 import dataclasses
@@ -52,7 +52,6 @@ from repro.telemetry import (
     FlightRecorder,
     StageTimers,
     decompose,
-    kernel_roofline_rows,
     rate_threshold,
     reconstruct,
     sample_mask,
@@ -65,7 +64,6 @@ from repro.telemetry.attribution import (
     B_RETRY,
     B_SERVICE,
 )
-from repro.telemetry.profiler import KERNELS
 
 SCFG = ScenarioConfig(n_epochs=6, epoch_ops=256, n_records=512,
                       value_dim=2, seed=3)
@@ -400,7 +398,7 @@ def test_chrome_trace_and_jsonl_exports(traced_run, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# profiler: stage timers + kernel roofline
+# profiler: stage timers and counters
 # ---------------------------------------------------------------------------
 
 
@@ -432,35 +430,14 @@ def test_driver_stage_timers_fire(traced_run):
     assert "stage_share" in drv.telemetry.summary()
 
 
-def test_kernel_roofline_rows_smoke(monkeypatch):
-    # give the running device (the CPU here) a peak table of its own, so
-    # the rows are priced against the device that ran them
-    kind = jax.devices()[0].device_kind
-    monkeypatch.setitem(MESH.PEAKS, kind, MESH.PEAKS["TPU v5 lite"])
-    rows = kernel_roofline_rows(batch=256, num_ranges=16, num_nodes=4,
-                                measure_iters=1)
-    assert [r["kernel"] for r in rows] == list(KERNELS)
-    for r in rows:
-        assert r["impl"] == "ref"
-        assert r["device_kind"] == kind
-        assert r["bytes"] > 0
-        # the routing kernels are integer-hash/compare/select lookups:
-        # no FP work, so they sit flat on the memory roof
-        assert r["flops"] >= 0
-        assert r["bound"] in ("memory", "compute")
-        assert r["roofline_us"] == max(r["t_compute_us"], r["t_memory_us"])
-        assert r["measured_us"] > 0
-        assert r["intensity_flop_per_byte"] == pytest.approx(
-            r["flops"] / r["bytes"])
-
-
 def test_kernel_roofline_rejects_unknown_device_kind():
     """Peaks are keyed by device kind: a device with no entry (the CPU
     here) is an error, never silently priced as a v5e."""
-    assert jax.devices()[0].device_kind not in MESH.PEAKS
+    kind = jax.devices()[0].device_kind
+    assert kind not in MESH.PEAKS
     with pytest.raises(ValueError, match="no peak table"):
-        kernel_roofline_rows(batch=256, num_ranges=16, num_nodes=4,
-                             measure_iters=1)
+        MESH.device_peaks(kind)
+    assert MESH.device_peaks("TPU v5 lite") is MESH.PEAKS["TPU v5 lite"]
 
 
 # ---------------------------------------------------------------------------
