@@ -294,9 +294,13 @@ class EpochDriver:
     ``timers`` is the :class:`~repro.telemetry.StageTimers` the driver
     times its stages and counts the store's work with (default: the span
     plane's when ``cfg.telemetry`` is set, else disabled ones).  Enabled,
-    each segment also adds the slab rows its period rewrote
-    (``slab_rows_rewritten``) and the PUT rows it applied at chain
-    members (``put_rows``) to ``timers.counts``.
+    each segment also adds its period's
+    :class:`~repro.core.store.ApplyCounts` to ``timers.counts``: the slab
+    rows it rewrote (``slab_rows_rewritten``: a whole slab for a delete
+    or a merge, the hit rows for PUTs written in place), the PUT rows it
+    applied at chain members (``put_rows``), and the shard batches whose
+    PUTs ran the O(capacity) merge (``put_merges``) or skipped it
+    because they inserted no new key (``put_in_place``).
     """
 
     def __init__(

@@ -59,7 +59,8 @@ def apply_migration(store: StoreState, lo, hi, src: jnp.ndarray, dst: jnp.ndarra
 
     # the extracted range is sorted and distinct: no dedupe sort (a sort
     # of a whole slab is minutes of TPU compile at a million slots)
-    dst_keys, dst_vals, dropped = put_sorted(store.keys[dst], store.values[dst], ex_keys, ex_vals)
+    dst_keys, dst_vals, dropped, _, _ = put_sorted(
+        store.keys[dst], store.values[dst], ex_keys, ex_vals)
     keys = store.keys.at[dst].set(dst_keys)
     values = store.values.at[dst].set(dst_vals)
 

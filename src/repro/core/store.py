@@ -11,11 +11,11 @@ a searchsorted **rank merge** of the two already-sorted runs (the slab and
 the deduped batch) — the moral equivalent of an SST memtable merge, at
 O(C+B) gather work (plus O(B log) binary searches) instead of a full
 O((C+B) log(C+B)) sort of the concatenation; the only scatter is the
-B-row in-place overwrite of keys a PUT batch already holds.  The merge
-reproduces the old
+B-row in-place overwrite of keys a PUT batch already holds, and a batch
+that inserts no new key skips the merge.  The merge reproduces the old
 sort-and-truncate layout exactly on the live prefix (asserted in
-``tests/test_store_merge.py``); dead tail slots now hold zeroed values
-instead of stale garbage — a deliberate tightening.  The jnp oracle
+``tests/test_store_merge.py``); dead tail slots hold zeroed values, and
+every operation keeps them so, which the skip relies on.  The jnp oracle
 (``apply_routed``), the ``shard_apply`` twin inside
 ``dist_store.make_dist_apply`` and the migration movers all share these
 primitives, so oracle/dist parity stays bit-exact.
@@ -121,13 +121,19 @@ class Responses:
 
 
 class ApplyCounts(NamedTuple):
-    """What applying one batch cost the slabs, summed over the shards:
-    ``slab_rows_rewritten`` counts the slab rows a delete or put branch
-    rewrote (the whole capacity of each shard whose branch ran), and
-    ``put_rows`` the PUT rows applied at chain members.  Both () int32."""
+    """What applying one batch cost the slabs, summed over the shards, all
+    () int32.  ``slab_rows_rewritten`` counts the slab rows a delete or
+    put branch rewrote: the whole capacity of a shard whose delete or
+    merge ran, the hit rows of one whose PUTs were all written in place.
+    ``put_rows`` counts the PUT rows applied at chain members;
+    ``put_merges`` the shard batches whose PUTs ran the O(capacity)
+    merge (they insert a key the shard lacks), ``put_in_place`` those
+    that skipped it."""
 
     slab_rows_rewritten: jnp.ndarray
     put_rows: jnp.ndarray
+    put_merges: jnp.ndarray
+    put_in_place: jnp.ndarray
 
 
 def make_store(num_shards: int, capacity: int, value_dim: int) -> StoreState:
@@ -317,36 +323,61 @@ def slab_put(slab_keys: jnp.ndarray, slab_vals: jnp.ndarray, put_keys: jnp.ndarr
     """Insert/overwrite a batch. Returns (keys, vals, dropped_count).
 
     The slab must be compact (live keys a sorted prefix, EMPTY tail), as
-    every store operation leaves it.  Keys already in the slab take their
-    new value in place (a B-row scatter); the new keys form a second
-    sorted run, and a searchsorted rank merge (:func:`_merge_sorted_runs`)
-    produces the combined sorted slab in O(C+B) gather work — no
-    log-factor sort of the concatenation and no O(C) compaction of the
-    slab.  Capacity overflow drops the largest keys and reports the
+    every store operation leaves it, with zero values on its EMPTY tail.
+    Keys already in the slab take their new value in place (a B-row
+    scatter); the new keys form a second sorted run, and a searchsorted
+    rank merge (:func:`_merge_sorted_runs`) produces the combined sorted
+    slab in O(C+B) gather work — no log-factor sort of the concatenation
+    and no O(C) compaction of the slab — run only when there is a new
+    key.  Capacity overflow drops the largest keys and reports the
     dropped count.
     """
     pk, pv = _dedupe_last_write(put_keys, put_vals)
-    return put_sorted(slab_keys, slab_vals, pk, pv)
+    return put_sorted(slab_keys, slab_vals, pk, pv)[:3]
 
 
 def put_sorted(slab_keys: jnp.ndarray, slab_vals: jnp.ndarray,
                pk: jnp.ndarray, pv: jnp.ndarray):
     """:func:`slab_put` of a batch that is already sorted with distinct
     keys, live keys a prefix (as :func:`_dedupe_last_write` or a
-    range extracted from a slab leaves it): no sort."""
+    range extracted from a slab leaves it): no sort.  Returns ``(keys,
+    vals, dropped, merged, rows)``: whether the batch ran the merge
+    (() bool) and the slab rows it rewrote (() int32).
+
+    Keys the slab already holds take their new value in place.  Only a
+    batch that inserts a key the slab lacks pays the O(C) merge; one that
+    only updates returns the slab's keys as they are and its values with
+    the hit rows written, ``rows`` the hit count.  That is bit for bit
+    what the merge gives, because the slab is compact with zero values on
+    its EMPTY tail, as :func:`make_store` and every store operation leave
+    it.  The skip is a ``lax.cond`` on a per-slab predicate: a caller that
+    vmaps this over slabs turns it into a select of both branches and
+    pays the merge on every slab again.
+    """
     C = slab_keys.shape[0]
     pos = jnp.minimum(jnp.searchsorted(slab_keys, pk), C - 1)
     hit = (slab_keys[pos] == pk) & (pk != EMPTY)
     slab_vals = slab_vals.at[jnp.where(hit, pos, C)].set(pv, mode="drop")
-    nk, nv = _compact_sorted(jnp.where(hit, EMPTY, pk), pv, ~hit & (pk != EMPTY))
-    # only the C smallest merged entries survive truncation: merge those
-    out_keys, out_vals = _merge_sorted_runs(slab_keys, slab_vals, nk, nv, C)
-    # dead tail slots hold zeros, whatever the slab's tail held
-    out_vals = jnp.where((out_keys != EMPTY)[:, None], out_vals, 0.0)
-    n_live = (jnp.sum((slab_keys != EMPTY).astype(jnp.int32))
-              + jnp.sum((nk != EMPTY).astype(jnp.int32)))
-    dropped = jnp.maximum(n_live - C, 0)
-    return out_keys, out_vals, dropped
+    new = ~hit & (pk != EMPTY)
+
+    def merge(kv):
+        keys, vals = kv
+        nk, nv = _compact_sorted(jnp.where(hit, EMPTY, pk), pv, new)
+        # only the C smallest merged entries survive truncation: merge those
+        out_keys, out_vals = _merge_sorted_runs(keys, vals, nk, nv, C)
+        # dead tail slots hold zeros
+        out_vals = jnp.where((out_keys != EMPTY)[:, None], out_vals, 0.0)
+        n_live = (jnp.sum((keys != EMPTY).astype(jnp.int32))
+                  + jnp.sum(new.astype(jnp.int32)))
+        return out_keys, out_vals, jnp.maximum(n_live - C, 0)
+
+    merged = jnp.any(new)
+    # nothing inserted: no row can drop, since the slab held at most C
+    out_keys, out_vals, dropped = jax.lax.cond(
+        merged, merge, lambda kv: (*kv, jnp.zeros((), jnp.int32)),
+        (slab_keys, slab_vals))
+    rows = jnp.where(merged, C, jnp.sum(hit.astype(jnp.int32)))
+    return out_keys, out_vals, dropped, merged, rows
 
 
 # ---------------------------------------------------------------------------
@@ -443,7 +474,10 @@ def shard_apply(
     # compaction, merge): each is skipped when the batch holds none.  The
     # predicates read the whole batch, so they stay a cond also where a
     # caller vmaps over shards (a per-shard predicate would turn into a
-    # select of both branches there).
+    # select of both branches there).  The merge skip inside the put
+    # branch is per shard: it holds because no caller vmaps over shards
+    # (``apply_routed_counted`` maps them one at a time, the dist path
+    # runs one per device).
     def scan(_):
         return slab_scan(
             slab_keys,
@@ -488,11 +522,13 @@ def shard_apply(
         with jax.named_scope(DEDUPE):
             pk, pv = _dedupe_last_write(put_keys, put_vals)
         with jax.named_scope(MERGE):
-            return (*put_sorted(*kv, pk, pv), rewrote)
+            keys, vals, dropped, merged, rows = put_sorted(*kv, pk, pv)
+        merged = merged.astype(jnp.int32)
+        return keys, vals, dropped, rows, merged, 1 - merged
 
-    slab_keys, slab_vals, dropped, put_rewrote = jax.lax.cond(
+    slab_keys, slab_vals, dropped, put_rewrote, merges, in_place = jax.lax.cond(
         jnp.any(q.opcode == K.OP_PUT), put,
-        lambda kv: (*kv, jnp.zeros((), jnp.int32), untouched),
+        lambda kv: (*kv, untouched, untouched, untouched, untouched),
         (slab_keys, slab_vals))
 
     resp = Responses(
@@ -503,7 +539,8 @@ def shard_apply(
         scan_count=scount,
     )
     counts = ApplyCounts(slab_rows_rewritten=del_rows + put_rewrote,
-                         put_rows=jnp.sum(is_put.astype(jnp.int32)))
+                         put_rows=jnp.sum(is_put.astype(jnp.int32)),
+                         put_merges=merges, put_in_place=in_place)
     return slab_keys, slab_vals, dropped, resp, counts
 
 

@@ -39,7 +39,8 @@ from repro.cluster.epoch import APPLY_SCOPES, PERIOD_SCOPES
 from repro.cluster.scenarios import Scenario
 from repro.core import keys as K
 from repro.core import routing as R
-from repro.core.store import get_digest, get_digest_np
+from repro.core.store import (APPLY, MERGE, get_digest, get_digest_np,
+                              put_sorted)
 from repro.telemetry import OpScopes, StageTimers, hlo_op_scopes, scope_seconds
 from repro.telemetry.profiler import STAGE_PREFIX, scope_path
 
@@ -58,13 +59,16 @@ def _ccfg(**kw):
 
 class _Scripted(Scenario):
     """Zipf traffic whose epochs follow a script of read shares (1.0: a
-    GET-only epoch), with every fifth op a GET of a key no record has."""
+    GET-only epoch), with every fifth op a GET of a key no record has;
+    with ``insert``, every seventh op of the epochs it names is a PUT of a
+    key no record has."""
 
     name = "scripted"
 
-    def __init__(self, cfg, read_shares):
+    def __init__(self, cfg, read_shares, insert=()):
         super().__init__(cfg)
         self.read_shares = read_shares
+        self.insert = insert
 
     def read_ratio(self, epoch):
         return self.read_shares[epoch % len(self.read_shares)]
@@ -75,8 +79,15 @@ class _Scripted(Scenario):
         absent = np.setdiff1d(self.record_keys + np.uint32(1),
                               self.record_keys)
         keys = np.where(miss, absent[e % len(absent)], keys)
-        opcodes = np.where(miss, K.OP_GET, opcodes).astype(np.int32)
-        return opcodes, keys.astype(np.uint32), end_keys, values
+        opcodes = np.where(miss, K.OP_GET, opcodes)
+        if e in self.insert:
+            fresh = np.arange(len(keys)) % 7 == 3
+            new = np.setdiff1d(self.record_keys + np.uint32(2),
+                               np.concatenate([self.record_keys, absent]))
+            keys = np.where(fresh, new[np.arange(len(keys)) % len(new)], keys)
+            opcodes = np.where(fresh, K.OP_PUT, opcodes)
+        return (opcodes.astype(np.int32), keys.astype(np.uint32), end_keys,
+                values)
 
 
 def _replay_digests(scen, n_epochs):
@@ -157,18 +168,74 @@ def _period_hlo(drv):
              drv.ovl, drv.coord, drv.metrics)
     text = drv._period_fn.lower(*state, *drv._period_inputs).compile().as_text()
     # the instructions without their metadata (and without the tables of
-    # source locations that follow the computations)
-    return re.sub(r", metadata=\{[^}]*\}", "", text.split("\nFileNames")[0])
+    # source locations that come before the computations)
+    text = re.sub(r"\n(?:FileNames|FunctionNames|FileLocations|StackFrames)"
+                  r"\n(?:\d+ .*\n)*", "\n", text)
+    return re.sub(r", metadata=\{[^}]*\}", "", text)
 
 
 def test_period_scopes_are_metadata_only(ran_driver, monkeypatch):
     scoped = _period_hlo(ran_driver)
+    assert " conditional(" in scoped and "FileNames" not in scoped
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     drv = EpochDriver(make_scenario("shifting_hotspot", SCFG),
                       make_policy("migrate"), _ccfg())
     drv._run_segment(0, SCFG.n_epochs)
     assert _period_hlo(drv) == scoped
+
+
+def _merge_conditionals(text):
+    """The ``conditional`` instructions of an optimized HLO module that
+    the put branch's merge skip emits: (op_name, the instructions of each
+    branch computation)."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.strip() == "}":
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    out = []
+    for ins in (i for body in comps.values() for i in body):
+        op = re.search(r'op_name="([^"]*)"', ins)
+        if " conditional(" in ins and op and op.group(1).endswith(
+                f"/{MERGE}/cond"):
+            names = re.search(r"branch_computations=\{([^}]*)\}", ins)
+            out.append((op.group(1), [comps[b.strip().lstrip("%")]
+                                      for b in names.group(1).split(",")]))
+    return out
+
+
+def test_merge_skip_is_a_conditional_inside_the_shard_map(ran_driver):
+    """The per-shard merge skip survives XLA as a ``conditional`` inside
+    the ``lax.map`` over shards: one branch holds the merge's search
+    loops, the other none.  A vmap over slabs would turn it into a
+    select of both branches, and the check sees that."""
+    state = (ran_driver.store, ran_driver.directory, ran_driver.load_reg,
+             ran_driver.sketch, ran_driver.repl, ran_driver.ovl,
+             ran_driver.coord, ran_driver.metrics)
+    text = ran_driver._period_fn.lower(
+        *state, *ran_driver._period_inputs).compile().as_text()
+    (op_name, branches), = _merge_conditionals(text)
+    assert f"/{APPLY}/while/body/" in op_name  # inside the map over shards
+    loops = sorted(sum(" while(" in i for i in b) for b in branches)
+    assert loops[0] == 0 and loops[1] > 0, loops
+
+    cap, v = ran_driver.store.capacity, ran_driver.store.value_dim
+    slabs = (jax.ShapeDtypeStruct((2, cap), jnp.uint32),
+             jax.ShapeDtypeStruct((2, cap, v), jnp.float32),
+             jax.ShapeDtypeStruct((2, 16), jnp.uint32),
+             jax.ShapeDtypeStruct((2, 16, v), jnp.float32))
+
+    def vmapped(*a):
+        with jax.named_scope(MERGE):
+            return jax.vmap(put_sorted)(*a)
+
+    assert _merge_conditionals(
+        jax.jit(vmapped).lower(*slabs).compile().as_text()) == []
 
 
 @pytest.mark.parametrize("op_name,path", [
@@ -273,33 +340,63 @@ def test_scope_seconds_add_up_to_the_period_module():
 # ---------------------------------------------------------------------------
 
 
+def _expected_counts(store_keys, opcodes, keys, decision):
+    """The :class:`~repro.core.store.ApplyCounts` of one epoch, from the
+    slabs before it: a shard whose PUTs bring a key it lacks merges
+    (its whole capacity rewritten), one whose PUTs all update keys it
+    holds writes its distinct PUT keys in place."""
+    store_keys = np.asarray(store_keys)
+    chain = np.asarray(decision.chain)
+    member = np.arange(chain.shape[1])[None, :] < np.asarray(
+        decision.chain_len)[:, None]
+    is_put = opcodes == K.OP_PUT
+    out = dict(slab_rows_rewritten=0, put_merges=0, put_in_place=0,
+               put_rows=int(member[is_put].sum()))
+    for s, slab in enumerate(store_keys):
+        mine = np.unique(keys[is_put & ((chain == s) & member).any(axis=1)])
+        if not len(mine):
+            continue
+        if np.isin(mine, slab, invert=True).any():
+            out["put_merges"] += 1
+            out["slab_rows_rewritten"] += slab.shape[0]
+        else:
+            out["put_in_place"] += 1
+            out["slab_rows_rewritten"] += len(mine)
+    return out
+
+
+@pytest.mark.parametrize("kind", ["update", "insert", "get_only"])
 @pytest.mark.parametrize("fused", [True, False])
-def test_slab_rewrite_counters(fused):
-    """An epoch with a PUT rewrites every shard's whole slab; a GET-only
-    epoch rewrites nothing; the PUT rows are the PUTs times their live
-    chain members."""
-    scen = _Scripted(SCFG, read_shares=[0.5, 1.0])
+def test_slab_rewrite_counters(fused, kind):
+    """An epoch whose PUTs all update held keys writes their rows in place
+    on every shard; one that inserts rewrites the whole slab of each shard
+    that receives a new key; a GET-only epoch rewrites nothing.  The PUT
+    rows are the PUTs times their live chain members."""
+    scen = _Scripted(SCFG, read_shares=[1.0 if kind == "get_only" else 0.5],
+                     insert=(0,) if kind == "insert" else ())
     timers = StageTimers(enabled=True)
     drv = EpochDriver(scen, make_policy("frozen"), _ccfg(report_every=1),
                       fused=fused, timers=timers)
     N, Cap = drv.store.num_shards, drv.store.capacity
-    for e, (rewritten, has_put) in enumerate([(N * Cap, True), (0, False)]):
-        opcodes, keys, end_keys, values = scen.epoch(e)
-        q = C.make_queries(jnp.asarray(keys), jnp.asarray(opcodes),
-                           jnp.asarray(values), jnp.asarray(end_keys))
-        decision, _ = R.route(drv.directory, q)
-        is_put = opcodes == K.OP_PUT
-        assert is_put.any() == has_put
-        members = int(np.asarray(decision.chain_len)[is_put].sum())
-        before = dict(timers.counts)
-        if fused:
-            drv._run_segment(e, SCFG.n_epochs)
-        else:
-            drv.run_epoch(e)
-        assert (timers.counts["slab_rows_rewritten"]
-                - before.get("slab_rows_rewritten", 0)) == rewritten
-        assert (timers.counts["put_rows"]
-                - before.get("put_rows", 0)) == members
+    opcodes, keys, end_keys, values = scen.epoch(0)
+    q = C.make_queries(jnp.asarray(keys), jnp.asarray(opcodes),
+                       jnp.asarray(values), jnp.asarray(end_keys))
+    decision, _ = R.route(drv.directory, q)
+    want = _expected_counts(drv.store.keys, opcodes, keys, decision)
+    if fused:
+        drv._run_segment(0, SCFG.n_epochs)
+    else:
+        drv.run_epoch(0)
+    assert timers.counts == want
+    if kind == "update":
+        assert want["put_in_place"] == N and want["put_merges"] == 0
+        assert 0 < want["slab_rows_rewritten"] <= want["put_rows"]
+    elif kind == "insert":
+        assert want["put_merges"] > 0
+        assert want["slab_rows_rewritten"] >= want["put_merges"] * Cap
+    else:
+        assert want == dict(slab_rows_rewritten=0, put_rows=0, put_merges=0,
+                            put_in_place=0)
     assert timers.summary()["counts"] == timers.counts
 
 
@@ -407,8 +504,7 @@ def test_dist_period_digest_counters_and_scopes():
             drv = EpochDriver(make_scenario("shifting_hotspot", scfg),
                               make_policy("migrate"), ccfg, backend=backend,
                               timers=t, **kw)
-            out[backend] = ([r.get_digest for r in drv.run()], t.counts,
-                            drv.store.capacity)
+            out[backend] = ([r.get_digest for r in drv.run()], t.counts)
         compiles = []
         jax.monitoring.register_event_duration_secs_listener(
             lambda e, d, **_: compiles.append(e) if e.endswith(
@@ -418,14 +514,18 @@ def test_dist_period_digest_counters_and_scopes():
         assert not compiles, compiles
         assert {p.split("/")[0] for p in paths if p} == set(PERIOD_SCOPES)
         assert "apply/exchange" in paths and "apply/merge" in paths
-        (od, oc, cap), (dd, dc, _) = out["oracle"], out["dist"]
+        (od, oc), (dd, dc) = out["oracle"], out["dist"]
         assert od == dd, (od, dd)
         assert all(od)
         assert oc["put_rows"] == dc["put_rows"] > 0, (oc, dc)
-        # each device rewrites its slab once per write round that
-        # brings it a PUT: whole slabs, as many as the oracle's or more
-        assert dc["slab_rows_rewritten"] % cap == 0
-        assert oc["slab_rows_rewritten"] == 4 * cap * 4
+        # every PUT updates a record its chain members hold: no shard
+        # merges, each writes its distinct PUT keys in place, on a device
+        # in one write round per chain position it holds in the batch
+        assert oc["put_merges"] == dc["put_merges"] == 0, (oc, dc)
+        assert oc["put_in_place"] == 4 * 4, oc
+        assert dc["put_in_place"] >= oc["put_in_place"], (oc, dc)
+        assert (oc["slab_rows_rewritten"] == dc["slab_rows_rewritten"]
+                <= oc["put_rows"]), (oc, dc)
         print("ok")
     """)
     env = {**os.environ,

@@ -8,8 +8,12 @@ merge of the two already-sorted runs.  These tests pin the contract:
 * dead tail: EMPTY keys with **zeroed** values (a deliberate tightening —
   the old path left stale garbage values behind);
 * overflow accounting identical;
-* the migration movers (which share ``_compact_sorted``) round-trip.
+* the migration movers (which share ``_compact_sorted``) round-trip;
+* a PUT batch that inserts no new key skips the merge and still gives,
+  bit for bit, what the merge path gives.
 """
+
+import zlib
 
 import numpy as np
 import jax
@@ -23,7 +27,10 @@ from repro.core.store import (
     _cumsum,
     _dedupe_last_write,
     _member_sorted,
+    _merge_sorted_runs,
+    delete_range,
     make_store,
+    put_sorted,
     slab_delete,
     slab_get,
     slab_put,
@@ -73,6 +80,9 @@ def _random_slab(rng, C, V, keyspace, fill=None):
         rng.choice(keyspace, size=n_live, replace=False).astype(np.uint32)
     )
     vals = rng.normal(size=(C, V)).astype(np.float32)
+    # zero values on the EMPTY tail, as make_store and every store
+    # operation leave a slab (a PUT batch that inserts nothing relies on it)
+    vals[n_live:] = 0.0
     return keys, vals
 
 
@@ -252,3 +262,172 @@ def test_slab_put_large_uint32_spans():
     np.testing.assert_array_equal(
         np.asarray(k)[:3], [0, 0x80000000, 0xFFFFFFFE])
     assert int(d) == 0
+
+
+# ---------------------------------------------------------------------------
+# the merge skip: a batch that inserts no new key is written in place
+# ---------------------------------------------------------------------------
+
+
+def _merge_path(sk, sv, pk, pv):
+    """The O(C) path of ``put_sorted`` called directly, whatever the
+    batch: the hit rows written in place, then ``_compact_sorted`` of the
+    new keys, ``_merge_sorted_runs`` and the tail zeroing."""
+    C = sk.shape[0]
+    pos = jnp.minimum(jnp.searchsorted(sk, pk), C - 1)
+    hit = (sk[pos] == pk) & (pk != EMPTY)
+    sv = sv.at[jnp.where(hit, pos, C)].set(pv, mode="drop")
+    new = ~hit & (pk != EMPTY)
+    nk, nv = _compact_sorted(jnp.where(hit, EMPTY, pk), pv, new)
+    k, v = _merge_sorted_runs(sk, sv, nk, nv, C)
+    v = jnp.where((k != EMPTY)[:, None], v, 0.0)
+    n_live = jnp.sum(sk != EMPTY) + jnp.sum(new)
+    return k, v, jnp.maximum(n_live - C, 0)
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.uint32) if x.dtype == np.float32 else x
+
+
+def _put_case(name, rng, C=32, V=3, B=16):
+    """(slab keys, slab values, raw PUT keys, PUT values, whether the batch
+    inserts a key the slab lacks)."""
+    fill = C if name.startswith("full") else 20
+    sk, sv = _random_slab(rng, C, V, keyspace=1000, fill=fill)
+    live = sk[sk != EMPTY]
+    fresh = np.setdiff1d(np.arange(1000, dtype=np.uint32), live)
+    old = rng.choice(live, 6, replace=False)
+    new = rng.choice(fresh, 6, replace=False)
+    keys = {
+        "update_only": old,
+        "insert_only": new,
+        "mixed": np.concatenate([old[:3], new[:3]]),
+        "duplicates_update": np.concatenate([old[:3], old[:3], old[:2]]),
+        "duplicates_mixed": np.concatenate([old[:3], new[:2], old[:3],
+                                            new[:2]]),
+        "all_empty": np.zeros(0, np.uint32),
+        "full_update": old,
+        "full_overflow": new,
+    }[name]
+    pkeys = np.full(B, EMPTY, np.uint32)
+    pkeys[:len(keys)] = keys
+    rng.shuffle(pkeys)
+    pvals = rng.normal(size=(B, V)).astype(np.float32)
+    return sk, sv, pkeys, pvals, bool(np.isin(keys, live, invert=True).any())
+
+
+@pytest.mark.parametrize("name", [
+    "update_only", "insert_only", "mixed", "duplicates_update",
+    "duplicates_mixed", "all_empty", "full_update", "full_overflow",
+])
+def test_put_skip_equals_full_merge(name):
+    """``put_sorted`` (and ``slab_put``, which dedupes first)
+    against the merge path called directly: keys, values and ``dropped``
+    bit for bit, the merge run only for a batch with a new key."""
+    sk, sv, pkeys, pvals, inserts = _put_case(
+        name, np.random.default_rng(zlib.crc32(name.encode())))
+    sk, sv = jnp.asarray(sk), jnp.asarray(sv)
+    pk, pv = _dedupe_last_write(jnp.asarray(pkeys), jnp.asarray(pvals))
+    want = _merge_path(sk, sv, pk, pv)
+    k, v, dropped, merged, rows = put_sorted(sk, sv, pk, pv)
+    for got in ((k, v, dropped),
+                slab_put(sk, sv, jnp.asarray(pkeys), jnp.asarray(pvals))):
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(_bits(g), _bits(w))
+    assert bool(merged) == inserts
+    slab = np.asarray(sk)
+    hits = int(np.isin(np.asarray(pk), slab[slab != EMPTY]).sum())
+    assert int(rows) == (sk.shape[0] if inserts else hits)
+    if name == "full_overflow":
+        assert int(dropped) == 6
+    if not inserts:
+        assert int(dropped) == 0
+        np.testing.assert_array_equal(np.asarray(k), np.asarray(sk))
+
+
+def test_migration_into_a_slab_that_holds_the_range():
+    """``apply_migration`` whose destination already holds every key of
+    the range (a copy, then a move): the values arrive in place, with the
+    slab as the merge path leaves it."""
+    from repro.core.migration import apply_migration
+    from repro.core.store import StoreState
+
+    rng = np.random.default_rng(6)
+    C, V = 48, 2
+    keys = np.sort(rng.choice(1000, 30, replace=False).astype(np.uint32))
+    store = make_store(3, C, V)
+    slabs_k, slabs_v = [], []
+    for shard, vals in enumerate((rng.normal(size=(30, V)),
+                                  rng.normal(size=(30, V)))):
+        k, v, _ = slab_put(store.keys[shard], store.values[shard],
+                           jnp.asarray(keys), jnp.asarray(vals, np.float32))
+        slabs_k.append(k)
+        slabs_v.append(v)
+    store = StoreState(keys=jnp.stack([*slabs_k, store.keys[2]]),
+                       values=jnp.stack([*slabs_v, store.values[2]]),
+                       overflow=store.overflow)
+    lo, hi = int(keys[5]), int(keys[20])
+    ex_live = (store.keys[0] >= lo) & (store.keys[0] <= hi)
+    ex_k, ex_v = _compact_sorted(jnp.where(ex_live, store.keys[0], EMPTY),
+                                 store.values[0], ex_live)
+    dst_k, dst_v, _ = _merge_path(store.keys[1], store.values[1], ex_k, ex_v)
+    for move in (False, True):
+        out = apply_migration(store, lo, hi, jnp.int32(0), jnp.int32(1),
+                              move=move)
+        np.testing.assert_array_equal(np.asarray(out.keys[1]), dst_k)
+        np.testing.assert_array_equal(_bits(out.values[1]), _bits(dst_v))
+        src_k, src_v = ((store.keys[0], store.values[0]) if not move else
+                        delete_range(store.keys[0], store.values[0], lo, hi))
+        np.testing.assert_array_equal(np.asarray(out.keys[0]), src_k)
+        np.testing.assert_array_equal(_bits(out.values[0]), _bits(src_v))
+        np.testing.assert_array_equal(np.asarray(out.overflow), 0)
+        # the range now carries the source's values at the destination
+        got, found = slab_get(out.keys[1], out.values[1],
+                              jnp.asarray(keys[5:21]))
+        assert bool(np.asarray(found).all())
+        np.testing.assert_array_equal(_bits(got), _bits(slabs_v[0][5:21]))
+
+
+def test_store_operations_keep_slabs_compact_with_zero_tails():
+    """The precondition of the merge skip: every operation leaves each
+    slab's live keys a strictly increasing prefix and zero values on its
+    EMPTY tail, through mixed PUT/DEL/GET batches on the served path and
+    every kind of migration."""
+    from repro import core as C
+    from repro.core import keys as K
+    from repro.core.migration import MigrationOp, execute
+    from repro.core.store import apply_routed
+
+    def assert_compact(store):
+        keys, vals = np.asarray(store.keys), np.asarray(store.values)
+        for k, v in zip(keys, vals):
+            n = int((k != EMPTY).sum())
+            assert (k[n:] == EMPTY).all()
+            assert (np.diff(k[:n].astype(np.int64)) > 0).all()
+            assert (v[n:] == 0).all()
+
+    rng = np.random.default_rng(8)
+    N, cap, V, B = 4, 96, 2, 64
+    store = make_store(N, cap, V)
+    directory = C.make_directory(8, N, 3, r_max=4, n_slots=16)
+    pool = rng.choice(2**31, 120, replace=False).astype(np.uint32)
+    ops = np.array([K.OP_PUT, K.OP_PUT, K.OP_DEL, K.OP_GET], np.int32)
+    for step in range(6):
+        # the first batch loads; later ones mix updates, inserts, deletes
+        opcodes = (np.full(B, K.OP_PUT, np.int32) if step == 0
+                   else rng.choice(ops, B).astype(np.int32))
+        q = C.make_queries(jnp.asarray(rng.choice(pool, B)),
+                           jnp.asarray(opcodes),
+                           jnp.asarray(rng.normal(size=(B, V)), jnp.float32))
+        decision, directory = C.route(directory, q)
+        store, _ = apply_routed(store, q, decision)
+        assert_compact(store)
+    live = np.asarray(store.keys[0])
+    live = live[live != EMPTY]
+    lo, hi = int(live[len(live) // 4]), int(live[3 * len(live) // 4])
+    # the second copy finds the range already at its destination
+    for src, dst, kind in ((0, 1, "copy"), (0, 1, "copy"), (0, 2, "move"),
+                           (1, 1, "reclaim")):
+        store = execute(store, [MigrationOp(lo, hi, src, dst, kind)])
+        assert_compact(store)

@@ -18,7 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import core as C
 from repro.cluster.epoch import PRELOAD_CHUNK, _preload_chunk
-from repro.core.store import apply_routed, make_store
+from repro.core.store import MERGE, apply_routed, make_store
 from repro.kernels.range_match import kernel as KR
 
 # served widths: one epoch batch, the slot pool, nodes, chain headroom
@@ -148,6 +148,9 @@ def test_apply_routed_compiles_at_smoke_size(spec):
     compiled = jax.jit(apply_routed).lower(_store_specs(spec), q,
                                            dec).compile()
     _fits_hbm(compiled)
+    # the TPU compiler keeps the per-shard merge skip a conditional
+    assert sum(" conditional(" in line and f'/{MERGE}/cond"' in line
+               for line in compiled.as_text().splitlines()) == 1
 
 
 def test_preload_chunk_compiles_at_smoke_size(spec):
