@@ -61,8 +61,9 @@ with the second-level :data:`APPLY_SCOPES`, ``observe``, ``commit``),
 and :meth:`EpochDriver.period_op_scopes` maps each op of the compiled
 period program to its stage.  Each epoch returns the digest of the GET
 replies it served (``EpochMetrics.get_digest``) and counts the slab rows
-it rewrote and the PUT rows it applied, which enabled stage timers add
-up (``StageTimers.counts``).
+it rewrote and the PUT rows it applied, and on a mesh the rows its
+all-to-all rounds sent, which enabled stage timers add up
+(``StageTimers.counts``).
 """
 
 from __future__ import annotations
@@ -1153,10 +1154,9 @@ class EpochDriver:
             )
             if not spread:
                 load_reg = load_reg + node_ops.astype(jnp.uint32)
-            counts = S.ApplyCounts(**{f: m[f] for f in S.ApplyCounts._fields})
             return (store, directory, load_reg, sketch, repl, ovl, coord,
                     metrics, plan, node_ops, m["bucket_overflow"], bounced,
-                    ostats, cstats, spans, digest_of(q, resp), counts)
+                    ostats, cstats, spans, digest_of(q, resp), m["counts"])
 
         return step
 
@@ -1220,15 +1220,24 @@ class EpochDriver:
 
     def _pull_apply(self, ovf, digest, counts):
         """The overflow totals and GET digests of a segment's epochs, in
-        one round trip; with the timers on, the segment's
-        :class:`~repro.core.store.ApplyCounts` ride along into
-        ``timers.counts``."""
+        one round trip; with the timers on, the segment's count parts
+        ride along into ``timers.counts``: each field summed, or taken at
+        its maximum where the part names it among its ``peaks``.  The
+        dist programs return a tuple of parts (``ApplyCounts`` and, under
+        ``bucket_a2a``, ``ExchangeCounts``); the oracle programs their one
+        ``ApplyCounts``, bare, so that their outputs stay as they were."""
         if not self._timers.enabled:
             ovf_h, digest_h = self._sync((ovf, digest))
         else:
             ovf_h, digest_h, counts_h = self._sync((ovf, digest, counts))
-            for name, n in counts_h._asdict().items():
-                self._timers.count(name, int(n.astype(np.int64).sum()))
+            parts = counts_h if self.backend == "dist" else (counts_h,)
+            for part in parts:
+                for name, n in part._asdict().items():
+                    n = n.astype(np.int64)
+                    if name in part.peaks:
+                        self._timers.peak(name, int(n.max()))
+                    else:
+                        self._timers.count(name, int(n.sum()))
         return ovf_h.astype(np.int64), digest_h
 
     def _note_period_inputs(self, qs, rngs, live, eids) -> None:

@@ -49,8 +49,9 @@ Two entry points share one per-device data plane (``_make_bucket_plane``):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -60,9 +61,50 @@ from repro.core import keys as K
 from repro.core import routing as R
 from repro.core.directory import Directory
 from repro.core import store as S
-from repro.core.store import ApplyCounts, StoreState, Responses, shard_apply
+from repro.core.store import StoreState, Responses, shard_apply
 
 DROP = -1  # bucket slot for dead/overflowed queries
+# third-level scopes under ``apply/exchange``: the read round and the
+# chain's write rounds
+READ_ROUND, WRITE_ROUND = "read", "write"
+
+
+class ExchangeCounts(NamedTuple):
+    """What one batch sent through the query all_to_all rounds, each an
+    (R,) int32 over the R = 1 + r_max rounds: round 0 is the read round,
+    round k the write round of chain position k - 1.  ``a2a_rows``
+    counts the live slots sent, ``a2a_rows_remote`` those whose target
+    is another device, ``a2a_slots`` every slot sent, live or not;
+    ``bucket_peak`` is the largest fill of any (source, target) bucket,
+    against ``DistConfig.bucket_cap``.  Over a mesh the rows and slots
+    are sums over the devices and the peak their maximum (see
+    :attr:`peaks`).  The replies ride back in the same slots."""
+
+    a2a_rows: jnp.ndarray
+    a2a_rows_remote: jnp.ndarray
+    a2a_slots: jnp.ndarray
+    bucket_peak: jnp.ndarray
+
+    # fields combined by their maximum, over devices and over epochs
+    peaks = frozenset({"bucket_peak"})
+
+
+def _round_counts(slot: jnp.ndarray, me, n_shards: int, cap: int):
+    """(live rows, remote rows, slots, peak bucket fill) of one round's
+    bucket slots on this device."""
+    live = slot >= 0
+    target = jnp.where(live, slot // cap, n_shards)
+    fill = jnp.zeros((n_shards,), jnp.int32).at[target].add(1, mode="drop")
+    return (jnp.sum(live, dtype=jnp.int32),
+            jnp.sum(live & (target != me), dtype=jnp.int32),
+            jnp.int32(n_shards * cap), jnp.max(fill))
+
+
+def _combine_exchange_counts(counts: ExchangeCounts, axis: str):
+    """One device's :class:`ExchangeCounts` combined over the mesh axis."""
+    return ExchangeCounts(*(
+        (jax.lax.pmax if f in counts.peaks else jax.lax.psum)(x, axis)
+        for f, x in zip(ExchangeCounts._fields, counts)))
 
 
 # ---------------------------------------------------------------------------
@@ -161,10 +203,11 @@ def _make_bucket_plane(cfg: DistConfig, n_shards: int):
 
     Returns ``plane(store, directory, q_local, load_reg, rng, dirty,
     queue_pen) -> (store', resp, directory', load_reg', decision, picked,
-    bounced, bucket_overflow, counts)``, ``counts`` this device's
-    :class:`~repro.core.store.ApplyCounts` over its read and write
-    rounds; ``load_reg``/``rng``/``dirty``/``queue_pen`` ride through
-    untouched on the paths that ignore them.
+    bounced, bucket_overflow, counts, xcounts)``, ``counts`` this
+    device's :class:`~repro.core.store.ApplyCounts` over its read and
+    write rounds and ``xcounts`` its :class:`ExchangeCounts`;
+    ``load_reg``/``rng``/``dirty``/``queue_pen`` ride through untouched
+    on the paths that ignore them.
     """
     axis = cfg.axis
     spread = cfg.read_spread
@@ -206,25 +249,31 @@ def _make_bucket_plane(cfg: DistConfig, n_shards: int):
                 + jax.lax.psum(directory.write_count - base_dir.write_count, axis),
             )
         with jax.named_scope(S.APPLY):
-            new_store, resp, bucket_ovf, counts = _apply(
+            new_store, resp, bucket_ovf, counts, xcounts = _apply(
                 store, q, decision, slab_keys, slab_vals)
         return (new_store, resp, directory, load_reg, decision, picked,
-                bounced, bucket_ovf, counts)
+                bounced, bucket_ovf, counts, xcounts)
+
+    @contextlib.contextmanager
+    def exchange(part: str):
+        with jax.named_scope(S.EXCHANGE), jax.named_scope(part):
+            yield
 
     def _apply(store, q, decision, slab_keys, slab_vals):
         """The read round, the ``r_max`` write rounds and the local slab
-        mutations: ``(store', resp, bucket_overflow, counts)``."""
+        mutations: ``(store', resp, bucket_overflow, counts, xcounts)``."""
+        me = jax.lax.axis_index(axis)
         is_write = (q.opcode == K.OP_PUT) | (q.opcode == K.OP_DEL)
         cap = cfg.bucket_cap
         n_slots = n_shards * cap
-        exchange = partial(jax.named_scope, S.EXCHANGE)
 
         # --- reads: one a2a round to the tail, replies via inverse a2a ---
-        with exchange():
+        with exchange(READ_ROUND):
             read_target = jnp.where(
                 ~is_write & (q.key != K.EMPTY_KEY), decision.target, DROP
             )
             slot, ovf_r = bucketize(read_target, n_shards, cap)
+            rounds = [_round_counts(slot, me, n_shards, cap)]
             bkeys = scatter_to_buckets(slot, q.key, n_slots, K.EMPTY_KEY)
             bop = scatter_to_buckets(slot, q.opcode, n_slots,
                                      jnp.int32(K.OP_GET))
@@ -243,9 +292,8 @@ def _make_bucket_plane(cfg: DistConfig, n_shards: int):
             jnp.zeros_like(read_mine),  # no writes in the read round
             max_scan_results=cfg.max_scan_results,
         )
-        # replies travel back through the inverse all_to_all
-        with exchange():
-            back = jax.tree.map(lambda x: _a2a(x, axis, n_shards), resp_in)
+        with exchange(READ_ROUND):
+            back = _return_replies(resp_in, axis, n_shards)
             resp = Responses(
                 value=gather_from_buckets(slot, back.value, 0.0),
                 found=gather_from_buckets(slot, back.found, False),
@@ -260,12 +308,13 @@ def _make_bucket_plane(cfg: DistConfig, n_shards: int):
         ovf_w = jnp.zeros((), ovf_r.dtype)
         r_max = decision.chain.shape[1]
         for pos in range(r_max):
-            with exchange():
+            with exchange(WRITE_ROUND):
                 live = (is_write & (pos < decision.chain_len)
                         & (q.key != K.EMPTY_KEY))
                 wt = jnp.where(live, decision.chain[:, pos], DROP)
                 wslot, ovf = bucketize(wt, n_shards, cap)
                 ovf_w += ovf
+                rounds.append(_round_counts(wslot, me, n_shards, cap))
                 wkeys = scatter_to_buckets(wslot, q.key, n_slots, K.EMPTY_KEY)
                 wop = scatter_to_buckets(wslot, q.opcode, n_slots,
                                          jnp.int32(K.OP_GET))
@@ -288,7 +337,7 @@ def _make_bucket_plane(cfg: DistConfig, n_shards: int):
             else:
                 put_dropped = put_dropped + dropped
             # tail replies: DEL found flag returns from the last chain pos
-            with exchange():
+            with exchange(WRITE_ROUND):
                 wback = _a2a(wresp.found, axis, n_shards)
                 at_tail = is_write & (pos == decision.chain_len - 1)
                 got = gather_from_buckets(wslot, wback, False)
@@ -300,7 +349,9 @@ def _make_bucket_plane(cfg: DistConfig, n_shards: int):
             keys=slab_keys[None], values=slab_vals[None],
             overflow=store.overflow + put_dropped,
         )
-        return (new_store, resp, (ovf_r + ovf_w).astype(jnp.int32), counts)
+        xcounts = ExchangeCounts(*(jnp.stack(c) for c in zip(*rounds)))
+        return (new_store, resp, (ovf_r + ovf_w).astype(jnp.int32), counts,
+                xcounts)
 
     return plane
 
@@ -308,6 +359,12 @@ def _make_bucket_plane(cfg: DistConfig, n_shards: int):
 def _a2a(x: jnp.ndarray, axis: str, n: int) -> jnp.ndarray:
     """(n, cap, ...) buckets -> transposed across the mesh axis."""
     return jax.lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=True)
+
+
+def _return_replies(resp: Responses, axis: str, n: int) -> Responses:
+    """The read round's replies, still in bucket slots, back to the
+    devices that sent the queries: the inverse all_to_all."""
+    return jax.tree.map(lambda x: _a2a(x, axis, n), resp)
 
 
 def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
@@ -325,8 +382,9 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
     globally consistent.  ``cfg.return_decision`` adds the sharded routing
     decision (target/chain/chain_len) to ``metrics`` so the caller can
     build DES hop plans without routing a second time.  ``metrics`` also
-    holds the batch's :class:`~repro.core.store.ApplyCounts` fields,
-    summed over the devices.
+    holds under ``counts`` the batch's count parts, combined over the
+    devices: ``(ApplyCounts,)``, and under ``bucket_a2a``
+    ``(ApplyCounts, ExchangeCounts)``.
     """
     n_shards = mesh.shape[cfg.axis]
     axis = cfg.axis
@@ -385,7 +443,7 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
             metrics = {
                 "bucket_overflow": overflow,
                 "a2a_rounds": jnp.zeros((), jnp.int32),
-                **_global_counts(counts),
+                "counts": (jax.lax.psum(counts, axis),),
             }
             if cfg.return_decision:
                 metrics.update(_slice_decision(decision, me, q.opcode.shape[0]))
@@ -403,13 +461,14 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
 
         # ---- bucket_a2a (the shared per-device data plane) ----
         (new_store, resp, directory, load_reg, decision, picked, bounced,
-         bucket_ovf, counts) = bucket_plane(
+         bucket_ovf, counts, xcounts) = bucket_plane(
             store, directory, q, load_reg, rng, dirty, queue_pen
         )
         metrics = {
             "bucket_overflow": bucket_ovf,
             "a2a_rounds": jnp.int32(1 + decision.chain.shape[1]),
-            **_global_counts(counts),
+            "counts": (jax.lax.psum(counts, axis),
+                       _combine_exchange_counts(xcounts, axis)),
         }
         if cfg.return_decision:
             metrics.update({
@@ -436,9 +495,6 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
 
     def _ag(x, ax):
         return jax.lax.all_gather(x, ax, axis=0, tiled=True)
-
-    def _global_counts(counts):
-        return jax.lax.psum(counts, axis)._asdict()
 
     def _mask_resp(x):
         if x.dtype == jnp.uint32:  # scan_keys sentinel: use min so EMPTY loses
@@ -472,8 +528,7 @@ def make_dist_apply(mesh, directory_template: Directory, cfg: DistConfig):
         value=P(axis), found=P(axis), scan_values=P(axis),
         scan_keys=P(axis), scan_count=P(axis),
     )
-    metric_spec = {"bucket_overflow": P(), "a2a_rounds": P(),
-                   **{f: P() for f in ApplyCounts._fields}}
+    metric_spec = {"bucket_overflow": P(), "a2a_rounds": P(), "counts": P()}
     if cfg.return_decision:
         metric_spec.update({"ridx": P(axis), "target": P(axis),
                             "chain": P(axis), "chain_len": P(axis)})
@@ -555,11 +610,13 @@ def make_dist_period(mesh, directory_template: Directory, cfg: DistConfig,
        qs, rngs, live, eids)
         -> (store, directory, load_reg, sketch, repl, ovl, coord, metrics,
             plans, node_ops, bucket_overflow, overflow_totals, bounced,
-            ostats, cstats, spans, get_digests, counts)
+            ostats, cstats, spans, get_digests, (counts, xcounts))
 
     with ``get_digests`` the (P,) :func:`~repro.core.store.get_digest` of
-    each epoch's GET replies and ``counts`` its
-    :class:`~repro.core.store.ApplyCounts`, both over all devices, ``qs`` the period's (P, B, ...) query pytree REPLICATED (each
+    each epoch's GET replies, ``counts`` its
+    :class:`~repro.core.store.ApplyCounts` and ``xcounts`` its
+    :class:`ExchangeCounts`, all over all devices, ``qs`` the period's
+    (P, B, ...) query pytree REPLICATED (each
     device slices its share for the data plane and keeps the whole batch
     for observe), ``live`` the (P,) real-epoch mask (dead padding epochs
     compute but do not commit), ``eids`` the (P,) absolute epoch ids.
@@ -596,7 +653,7 @@ def make_dist_period(mesh, directory_template: Directory, cfg: DistConfig,
                 lambda x: jax.lax.dynamic_slice_in_dim(x, me * Bl, Bl, 0), q
             )
             (store2, resp, directory2, load_reg2, decision, picked,
-             bounced, bucket_ovf, counts) = plane(
+             bounced, bucket_ovf, counts, xcounts) = plane(
                 store, directory, q_local, load_reg, r_route, dirty,
                 queue_pen,
             )
@@ -605,7 +662,8 @@ def make_dist_period(mesh, directory_template: Directory, cfg: DistConfig,
                 digest = jax.lax.psum(S.get_digest(
                     q_local.opcode, q_local.key, resp.value, resp.found,
                     base=me * Bl), axis)
-            counts = jax.lax.psum(counts, axis)
+            counts = (jax.lax.psum(counts, axis),
+                      _combine_exchange_counts(xcounts, axis))
             # reconstruct the global decision for the replicated observe
             with jax.named_scope(S.OBSERVE):
                 ag = lambda x: jax.lax.all_gather(x, axis, axis=0, tiled=True)

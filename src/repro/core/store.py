@@ -135,6 +135,9 @@ class ApplyCounts(NamedTuple):
     put_merges: jnp.ndarray
     put_in_place: jnp.ndarray
 
+    # fields combined by their maximum rather than their sum: none
+    peaks = frozenset()
+
 
 def make_store(num_shards: int, capacity: int, value_dim: int) -> StoreState:
     return StoreState(

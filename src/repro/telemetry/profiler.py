@@ -63,6 +63,12 @@ class StageTimers:
         if self.enabled:
             self.counts[name] = self.counts.get(name, 0) + int(n)
 
+    def peak(self, name: str, n: int) -> None:
+        """Raise the counter ``name`` to ``n`` if it is below (nothing
+        when disabled): a maximum, not a sum."""
+        if self.enabled:
+            self.counts[name] = max(self.counts.get(name, int(n)), int(n))
+
     def summary(self) -> dict:
         total = sum(self.totals.values())
         return {
